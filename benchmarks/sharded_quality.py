@@ -28,7 +28,8 @@ def run(B=8, T=512, steps=32, quiet=False):
     from repro.core.retrieval import make_retriever
 
     cfg = get_config("granite-3-8b-smoke")
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = jax.make_mesh((1, 8), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     p = 16
     # pool pages must divide the model axis: pad via pool_pad_pages
     base = dict(method="freekv", page_size=p, budget=128 + 2 * p,

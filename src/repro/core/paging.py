@@ -118,11 +118,17 @@ def init_kv_state(cfg: ArchConfig, fkv: FreeKVConfig, batch: int, max_len: int,
 # layers. Continuous batching maps logical requests onto physical batch slots
 # by functionally splicing one row in or out; XLA lowers these to in-place
 # dynamic-update-slices so a slot refill never copies the other slots' pools.
-def slot_write_leaf(dst, src, slot, axis=0):
+def slot_write_leaf(dst, src, slot, axis=0, interpret=None, sharding=None):
     """Write ``src``'s singleton batch row into ``dst``'s row ``slot``.
 
     dst (..., B, ...) with batch on ``axis``; src identical but batch size 1;
-    ``slot`` may be a traced scalar (one compile serves every slot)."""
+    ``slot`` may be a traced scalar (one compile serves every slot). A
+    host-resident ``dst`` (the offloaded pool) is written by DMA in its word
+    format (``offload.write_slot``, kernel mode ``interpret``, per shard of
+    ``sharding`` under a mesh)."""
+    from repro.core import offload
+    if offload.is_host(dst):
+        return offload.write_slot(dst, src, slot, axis, interpret, sharding)
     upd = jax.lax.index_in_dim(src, 0, axis, keepdims=False).astype(dst.dtype)
     return jax.lax.dynamic_update_index_in_dim(dst, upd, slot, axis)
 
@@ -145,6 +151,52 @@ def nhd_pages_to_hnd(k_pages, v_pages):
 def hnd_to_nhd_kv(block):
     """pool block (B, ..., kv, 2, p, d) -> (k, v) each (B, ..., kv, p, d)."""
     return block[..., 0, :, :], block[..., 1, :, :]
+
+
+# Host-resident pools hold each (2, p, d) K+V block as rows of 32-bit words:
+# the TPU's host DMA moves whole (8, 512)-word tiles only, and refuses 16-bit
+# element types outright. A 16-bit block packs K into the low and V into the
+# high half of each word (elementwise, so no lane relayout); a 32-bit block
+# is K's rows then V's. Exact both ways, and one contiguous DMA per block.
+WORD_LANES = 512
+
+
+def word_rows(p: int, d: int, itemsize: int) -> int:
+    """Rows of WORD_LANES uint32 words that hold one (2, p, d) block; raises
+    when the block does not fill whole (8, WORD_LANES) DMA tiles."""
+    nbytes = 2 * p * d * itemsize
+    if itemsize not in (2, 4) or nbytes % (8 * WORD_LANES * 4):
+        raise NotImplementedError(
+            f"host pool blocks must be whole {8 * WORD_LANES * 4}-byte DMA "
+            f"tiles of 16/32-bit elements; (2, p={p}, d={d}) at {itemsize} "
+            f"bytes is {nbytes}")
+    return nbytes // (4 * WORD_LANES)
+
+
+def hnd_to_words(block):
+    """(..., 2, p, d) 16/32-bit blocks -> (..., rows, WORD_LANES) uint32."""
+    *lead, _, p, d = block.shape
+    rows = word_rows(p, d, block.dtype.itemsize)
+    if block.dtype.itemsize == 4:
+        words = jax.lax.bitcast_convert_type(block, jnp.uint32)
+    else:
+        k, v = (jax.lax.bitcast_convert_type(h, jnp.uint16).astype(jnp.uint32)
+                for h in hnd_to_nhd_kv(block))
+        words = k | (v << 16)
+    return words.reshape(*lead, rows, WORD_LANES)
+
+
+def words_to_kv(words, dtype, p: int, d: int):
+    """Inverse of ``hnd_to_words``: (..., rows, WORD_LANES) -> (k, v), each
+    (..., p, d) of ``dtype``."""
+    lead = words.shape[:-2]
+    if jnp.dtype(dtype).itemsize == 4:
+        blk = jax.lax.bitcast_convert_type(words, dtype).reshape(*lead, 2, p, d)
+        return hnd_to_nhd_kv(blk)
+    w = words.reshape(*lead, p, d)
+    half = lambda x: jax.lax.bitcast_convert_type(  # noqa: E731
+        x.astype(jnp.uint16), dtype)
+    return half(w & 0xFFFF), half(w >> 16)
 
 
 # ---------------------------------------------------------------------------
@@ -203,15 +255,19 @@ def prefill_fill_pool(state, k, v, length):
 # ---------------------------------------------------------------------------
 # decode-time append + page offload (NHD -> HND transpose amortized here)
 # ---------------------------------------------------------------------------
-def append_token(state, k_new, v_new):
+def append_token(state, k_new, v_new, interpret=None):
     """Append one token's K/V (B, kv, d); offload a page when one completes.
 
     The page completion test is per-row; the pool scatter is masked so rows not
     at a page boundary write nothing (a no-op row writes to its current page
     position with zero-effect data is avoided via index clamping + where).
+    A host-resident pool is written by DMA (``offload.write_page``, kernel
+    mode ``interpret``).
     """
+    from repro.core import offload
     B, n_win, kv, d = state["win_k"].shape
-    p = state["pool"].shape[4]
+    host = offload.is_host(state["pool"])
+    p = state["sel_k"].shape[3] if host else state["pool"].shape[4]
     pos = state["length"]                          # (B,) position of new token
     slot = pos % n_win
     bidx = jnp.arange(B)
@@ -232,6 +288,17 @@ def append_token(state, k_new, v_new):
     summ = jnp.stack([pk.min(axis=1), pk.max(axis=1)], axis=2)    # (B,kv,2,d)
 
     tgt = jnp.where(page_done, page_idx, 0)
+    summaries = state["summ"].at[bidx, tgt].set(jnp.where(
+        page_done[:, None, None, None], summ.astype(state["summ"].dtype),
+        jnp.take_along_axis(state["summ"], tgt[:, None, None, None, None],
+                            axis=1)[:, 0]))
+    rest = dict(win_k=win_k, win_v=win_v, win_pos=win_pos, summ=summaries,
+                length=new_len)
+    if host:                  # masked DMA write; rows without a page skip it
+        pool = offload.write_page(state["pool"], hnd.astype(state["sel_k"].dtype),
+                                  tgt, page_done, state.get("pool_layer"),
+                                  interpret)
+        return dict(state, pool=pool, **rest)
     qi = quant_info(state)
     if qi is None:
         blk = hnd.astype(state["pool"].dtype)
@@ -245,12 +312,6 @@ def append_token(state, k_new, v_new):
             jnp.where(page_done[:, None, None, None], qsc, old_sc_row))}
     old_pool_row = jnp.take_along_axis(
         state["pool"], tgt[:, None, None, None, None, None], axis=1)[:, 0]
-    old_summ_row = jnp.take_along_axis(
-        state["summ"], tgt[:, None, None, None, None], axis=1)[:, 0]
-    sel = page_done[:, None, None, None, None]
     pool = state["pool"].at[bidx, tgt].set(
-        jnp.where(sel, blk, old_pool_row))
-    summaries = state["summ"].at[bidx, tgt].set(
-        jnp.where(sel[..., 0], summ.astype(state["summ"].dtype), old_summ_row))
-    return dict(state, win_k=win_k, win_v=win_v, win_pos=win_pos,
-                pool=pool, summ=summaries, **scale_update, length=new_len)
+        jnp.where(page_done[:, None, None, None, None], blk, old_pool_row))
+    return dict(state, pool=pool, **scale_update, **rest)

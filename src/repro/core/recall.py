@@ -12,8 +12,7 @@ its transfers through that executor.
 from __future__ import annotations
 
 import jax.numpy as jnp
-
-from repro.compat import shard_map
+from jax import shard_map
 
 
 def recall_pages(pool, idx):

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, FreeKVConfig
-from repro.core import centroid_index, paging, recall, selection
+from repro.core import centroid_index, offload, paging, recall, selection
 from repro.core.correction import corrected_heads
 from repro.core.recall_pipeline import RecallExecutor, match_resident
 from repro.models.layers import softcap
@@ -184,15 +184,25 @@ class FreeKVRetriever:
         return (self.fkv.recall_overlap and self.speculative
                 and self.mesh is None)
 
+    def _interpret(self):
+        from repro.kernels import ops
+        return ops.resolve_interpret(self.fkv)
+
     def _pool_view(self, state):
         """The opaque pool reference the recall executor threads through:
-        the fp pool array, or a (packed pool, fp32 scales) pair under the
-        quantized host tier — every gather backend unpacks the same way."""
+        the fp pool array, a ``HostPool`` view of a host-resident pool, or a
+        (packed pool, fp32 scales) pair under the quantized host tier —
+        every gather backend unpacks the same way."""
+        host = offload.host_view(state, self._interpret())
+        if host is not None:
+            return host
         if "pool_scale" in state:
             return (state["pool"], state["pool_scale"])
         return state["pool"]
 
     def _recall_values(self, pool, idx):
+        if isinstance(pool, offload.HostPool):        # DMA is the only way in
+            return offload.recall_host(pool, idx)[1]
         if isinstance(pool, tuple):                   # quantized host tier
             pool_q, scales = pool
             if self.use_kernels:
@@ -211,6 +221,8 @@ class FreeKVRetriever:
         return recall.recall_values_only(pool, idx)
 
     def _recall(self, pool, idx):
+        if isinstance(pool, offload.HostPool):        # DMA is the only way in
+            return offload.recall_host(pool, idx)
         if isinstance(pool, tuple):                   # quantized host tier
             # fused dequant-on-recall: packed page + scales move, bf16/fp
             # never does. The page-sharded shard_map gather is fp-only; under
@@ -316,7 +328,8 @@ class FreeKVRetriever:
                     "granularity": "page"}
             return o, state, info
 
-        state = paging.append_token(state, k_new, v_new)
+        state = paging.append_token(state, k_new, v_new,
+                                    interpret=self._interpret())
         state = self._post_append(state)
         B = q.shape[0]
 

@@ -50,8 +50,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig, FreeKVConfig
 from repro.core import selection
 from repro.models.layers import softcap as _softcap
@@ -90,7 +90,14 @@ def tp_state_specs(cfg: ArchConfig, mesh, state):
     from repro.sharding import rules
 
     def f(path, leaf):
-        return rules.decode_state_spec(cfg, mesh, rules._path_str(path), leaf)
+        key = rules._path_str(path)
+        if key == "pool_layer":
+            return P()
+        if key == "pool" and "pool_layer" in state:
+            # the layer-stacked host pool rides whole (``core/offload.
+            # layer_state``): period axis leading, like a pattern leaf
+            key = "pattern/pool"
+        return rules.decode_state_spec(cfg, mesh, key, leaf)
 
     return jax.tree_util.tree_map_with_path(f, state)
 

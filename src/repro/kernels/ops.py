@@ -49,11 +49,7 @@ def page_summary(k, *, page_size, interpret=None):
 
 def page_scores(q, summ, *, scale, block_pages=128, interpret=None):
     interpret = _default_interpret() if interpret is None else interpret
-    N = summ.shape[1]
-    bp = block_pages
-    while N % bp:
-        bp //= 2
-    return _scores(q, summ, scale=scale, block_pages=max(bp, 1),
+    return _scores(q, summ, scale=scale, block_pages=block_pages,
                    interpret=interpret)
 
 

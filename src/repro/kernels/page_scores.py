@@ -2,9 +2,14 @@
 
 score[g, n] = scale * sum_d max(q[g,d] * lo[n,d], q[g,d] * hi[n,d])
 
-Grid tiles the page axis in blocks of 128 (lane-aligned); q's GQA group rides
-the sublane dim. This runs off the critical path under speculative retrieval
-but on it for corrected heads, so it is a genuine hot spot at 16K+ pages.
+Grid tiles the page axis in blocks of 128 (lane-aligned) where 128 divides
+it, and takes the whole axis otherwise; q's GQA group rides the sublane dim.
+The summaries are read in their state layout, (B, n_pages, kv, 2, d), with
+the KV head squeezed out of the block: a grid step reads one head's
+(pages, 2, d) tile, so the compiled program moves the summaries once, with
+no transpose or relayout copy in HBM. This runs off the critical path under
+speculative retrieval but on it for corrected heads, so it is a genuine hot
+spot at 16K+ pages.
 """
 from __future__ import annotations
 
@@ -15,10 +20,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(q_ref, lo_ref, hi_ref, o_ref, *, scale):
+def _kernel(q_ref, s_ref, o_ref, *, scale):
     q = q_ref[0, 0].astype(jnp.float32)            # (G, d)
-    lo = lo_ref[0, :, 0].astype(jnp.float32)       # (NB, d)
-    hi = hi_ref[0, :, 0].astype(jnp.float32)
+    lo = s_ref[0, :, 0, :].astype(jnp.float32)     # (NB, d)
+    hi = s_ref[0, :, 1, :].astype(jnp.float32)
     # sum_d max(q*lo, q*hi) == relu(q) @ hi^T + min(q,0) @ lo^T  (lo <= hi)
     dot = lambda a, b: jax.lax.dot_general(
         a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -44,19 +49,17 @@ def page_scores(q, summ, *, scale, block_pages=128, interpret=None):
         interpret = default_interpret()
     B, kv, G, d = q.shape
     N = summ.shape[1]
-    NB = min(block_pages, N)
-    assert N % NB == 0, (N, NB)
-    lo, hi = summ[..., 0, :], summ[..., 1, :]      # (B, N, kv, d)
+    NB = block_pages if N % block_pages == 0 else N
     kern = functools.partial(_kernel, scale=scale)
     return pl.pallas_call(
         kern,
         grid=(B, kv, N // NB),
         in_specs=[
             pl.BlockSpec((1, 1, G, d), lambda b, k, j: (b, k, 0, 0)),
-            pl.BlockSpec((1, NB, 1, d), lambda b, k, j: (b, j, k, 0)),
-            pl.BlockSpec((1, NB, 1, d), lambda b, k, j: (b, j, k, 0)),
+            pl.BlockSpec((1, NB, None, 2, d),
+                         lambda b, k, j: (b, j, k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, NB), lambda b, k, j: (b, k, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, kv, G, N), jnp.float32),
-        interpret=interpret,
-    )(q, lo, hi)
+        interpret=interpret, name="page_scores",
+    )(q, summ)

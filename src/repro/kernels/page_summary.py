@@ -11,13 +11,16 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(k_ref, o_ref):
-    k = k_ref[0, :, 0, :]                        # (p, d)
+    k = k_ref[0, 0]                              # (p, d)
     o_ref[0, 0, 0, 0] = jnp.min(k, axis=0)
     o_ref[0, 0, 0, 1] = jnp.max(k, axis=0)
 
 
 def page_summary(k, *, page_size, interpret=True):
-    """k (B, T, kv, d) with T = n_pages * p -> (B, n_pages, kv, 2, d)."""
+    """k (B, T, kv, d) with T = n_pages * p -> (B, n_pages, kv, 2, d).
+
+    The keys enter KV-head-major, (B, kv, T, d), so each grid step reads one
+    (p, d) page tile."""
     B, T, kv, d = k.shape
     p = page_size
     assert T % p == 0
@@ -25,9 +28,9 @@ def page_summary(k, *, page_size, interpret=True):
     return pl.pallas_call(
         _kernel,
         grid=(B, N, kv),
-        in_specs=[pl.BlockSpec((1, p, 1, d), lambda b, n, h: (b, n, h, 0))],
+        in_specs=[pl.BlockSpec((1, 1, p, d), lambda b, n, h: (b, h, n, 0))],
         out_specs=pl.BlockSpec((1, 1, 1, 2, d),
                                lambda b, n, h: (b, n, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N, kv, 2, d), k.dtype),
-        interpret=interpret,
-    )(k)
+        interpret=interpret, name="page_summary",
+    )(k.transpose(0, 2, 1, 3))

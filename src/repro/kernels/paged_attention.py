@@ -2,16 +2,22 @@
 
 This is FreeKV's decode hot spot: one query token per request attends to the
 budget-resident pages (sink + window + speculatively recalled), laid out NHD
-(page-major (p, d) blocks). Flash-style online softmax over a page-grid:
+(page-major (p, d) blocks). Flash-style online softmax over a token grid:
 
-  grid = (B, kv, N_pages); each step loads one (p, d) K page and V page into
-  VMEM, updates running (m, l, acc) scratch for all G group queries, and the
-  final step writes acc/l. Pallas pipelines the (b, kv, n) grid, so page n+1's
-  HBM->VMEM DMA overlaps page n's compute — the on-chip mirror of the paper's
-  double-buffered streamed recall.
+  grid = (B, kv, ceil(L / blk)); each step loads a (blk, d) run of K and V
+  tokens into VMEM and updates running (m, l, acc) scratch for all G group
+  queries; the last step writes acc/l. Pallas pipelines the grid, so block
+  j+1's HBM->VMEM DMA overlaps block j's compute.
 
-Tiling: p=32 x d=128 blocks are MXU/lane aligned; G (GQA group) rides in the
-sublane dimension of the q block.
+Tiling: the pages are passed flattened to (L = N·p, d) per head and their
+positions as a (1, L) row. A resident set of up to ``max_block`` tokens (the
+paper's 2048-token budget plus sink and window) is one whole-axis block per
+(b, kv): few grid steps, whose fixed cost outweighs a block's DMA at decode
+sizes. Larger sets stream in ``max_block``-token blocks (a multiple of 128,
+so the position row stays lane-aligned) and VMEM stays bounded; a ragged
+last block is masked by token index. The G query heads of a GQA group ride
+the sublane dimension of the q block. The current position arrives by
+scalar prefetch (SMEM).
 """
 from __future__ import annotations
 
@@ -25,72 +31,77 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, scale, softcap, n_pages):
-    n = pl.program_id(2)
+def _kernel(cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref,
+            acc_ref, *, scale, softcap, n_tokens):
+    b, j = pl.program_id(0), pl.program_id(2)
 
-    @pl.when(n == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)            # (G, d)
-    k = k_ref[0, 0, 0].astype(jnp.float32)         # (p, d)
-    v = v_ref[0, 0, 0].astype(jnp.float32)         # (p, d)
-    pos = pos_ref[0, 0, 0]                         # (p,) int32
-    cur = cur_ref[0]
+    k = k_ref[0, 0].astype(jnp.float32)            # (blk, d)
+    v = v_ref[0, 0].astype(jnp.float32)            # (blk, d)
+    pos = pos_ref[0, 0]                            # (1, blk) int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale  # (G,p)
+                            preferred_element_type=jnp.float32) * scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    ok = (pos >= 0) & (pos <= cur)
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    ok = (pos >= 0) & (pos <= cur_ref[b])
+    blk = k.shape[0]
+    if n_tokens % blk:
+        # ragged last block: lanes past L hold stale VMEM (maybe NaN)
+        start = j * blk
+        ok &= start + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1) \
+            < n_tokens
+        v = jnp.where(start + jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+                      < n_tokens, v, 0.0)
+    s = jnp.where(ok, s, NEG_INF)                  # (G, blk)
 
-    m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-    m_cur = jnp.max(s, axis=1)                     # (G,)
-    m_new = jnp.maximum(m_prev[:, 0], m_cur)
-    alpha = jnp.exp(m_prev[:, 0] - m_new)
-    pexp = jnp.exp(s - m_new[:, None])             # (G, p)
-    l_new = l_prev[:, 0] * alpha + jnp.sum(pexp, axis=1)
-    acc_new = acc_prev * alpha[:, None] + jax.lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new[:, None]
-    l_ref[...] = l_new[:, None]
-    acc_ref[...] = acc_new
+    m_prev = m_ref[...]                            # (G, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    w = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        w, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
-    @pl.when(n == n_pages - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, page_pos, cur_pos, *, scale,
-                    softcap=None, interpret=True):
+                    softcap=None, interpret=True, max_block=2304):
     """q (B,kv,G,d); k/v_pages (B,kv,N,p,d); page_pos (B,kv,N,p);
-    cur_pos (B,) -> (B,kv,G,d)."""
+    cur_pos (B,) -> (B,kv,G,d). ``max_block`` (a multiple of 128) bounds the
+    tokens held in VMEM per grid step."""
     B, kv, G, d = q.shape
-    N, p = k_pages.shape[2], k_pages.shape[3]
-    grid = (B, kv, N)
-    kern = functools.partial(_kernel, scale=scale, softcap=softcap, n_pages=N)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
+    L = k_pages.shape[2] * k_pages.shape[3]
+    blk = L if L <= max_block else max_block
+    kern = functools.partial(_kernel, scale=scale, softcap=softcap,
+                             n_tokens=L)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, kv, pl.cdiv(L, blk)),
         in_specs=[
-            pl.BlockSpec((1, 1, G, d), lambda b, k, n: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, 1, p, d), lambda b, k, n: (b, k, n, 0, 0)),
-            pl.BlockSpec((1, 1, 1, p, d), lambda b, k, n: (b, k, n, 0, 0)),
-            pl.BlockSpec((1, 1, 1, p), lambda b, k, n: (b, k, n, 0)),
-            pl.BlockSpec((1,), lambda b, k, n: (b,)),
+            pl.BlockSpec((1, 1, G, d), lambda b, k, j, cur: (b, k, 0, 0)),
+            pl.BlockSpec((1, 1, blk, d), lambda b, k, j, cur: (b, k, j, 0)),
+            pl.BlockSpec((1, 1, blk, d), lambda b, k, j, cur: (b, k, j, 0)),
+            pl.BlockSpec((1, 1, 1, blk), lambda b, k, j, cur: (b, k, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, d), lambda b, k, n: (b, k, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, G, d), lambda b, k, j, cur: (b, k, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, d), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kv, G, d), q.dtype),
-        scratch_shapes=[
-            # (G,1) running max / denom + (G,d) accumulator, fp32 in VMEM
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k_pages, v_pages, page_pos, cur_pos)
+        interpret=interpret, name="paged_attention",
+    )(cur_pos.astype(jnp.int32), q, k_pages.reshape(B, kv, L, d),
+      v_pages.reshape(B, kv, L, d), page_pos.reshape(B, kv, 1, L))

@@ -10,9 +10,11 @@ moves the maximal contiguous unit, the ``(2, p, d)`` HND K+V block
 (16 KiB at p=32, d=128, bf16). The page ids arrive as a SCALAR-PREFETCH
 operand so the copy source addresses are computable before the body runs.
 
-The pool stays in ``pltpu.ANY`` memory space ((host-mapped) HBM — see
-``core/offload.py``); the *staging* footprint is the ring alone (2 chunks of
-pages), independent of the selection budget. The per-(b, h) output blocks
+The pool stays in device memory (``pl.ANY``); a ``pinned_host`` pool is read
+by ``kernels/host_pages.recall_gather_host`` instead, since the TPU cannot
+DMA from host memory into VMEM (``core/offload.py``). The *staging*
+footprint is the ring alone (2 chunks of pages), independent of the
+selection budget. The per-(b, h) output blocks
 are ``(n_sel, p, d)`` and do scale with the budget — at production shapes
 (n_sel=32, p=32, d=128, bf16) that is 256 KiB per output, well under VMEM.
 
@@ -29,8 +31,9 @@ Contract (shared with ``core/recall.recall_pages`` and
 on vs off the decode critical path lives in ``core/recall_pipeline.py``.
 
 ``recall_gather_quant`` is the quantized-pool variant (``src/repro/quant``):
-the packed int8/int4 page and its fp32 scales ride the same ring as two DMAs
-per lane, and dequantization to the output dtype is fused into the drain —
+the packed int8/int4 page rides the same ring, its fp32 scales arrive as one
+pre-gathered block, and dequantization to the output dtype is fused into the
+drain —
 the transfer moves 2-4x fewer bytes and the fp page never exists outside
 VMEM. Parity vs the jnp dequant reference: ``tests/test_quant.py``.
 """
@@ -107,14 +110,17 @@ def _kernel(idx_ref, pool_ref, k_ref, v_ref, scratch, sems, *,
 
 
 def _quant_kernel(idx_ref, pool_ref, scale_ref, k_ref, v_ref,
-                  scratch, sscratch, sems, ssems, *,
+                  scratch, sems, *,
                   n_sel, n_pages, chunk, n_chunks, values_only, bits,
                   out_dtype):
-    """Quantized-pool variant: DMA the packed int page AND its fp32 scales
-    through the same 2-deep VMEM ring, dequantize on drain (fused — the fp
-    page never exists in host or HBM, only in VMEM on its way to the output
-    buffer). Dequant math matches ``repro.quant.quantizers.dequant_block``
-    exactly: int -> f32 * scale -> out_dtype."""
+    """Quantized-pool variant: DMA the packed int page through the 2-deep
+    VMEM ring and dequantize on drain (fused — the fp page never exists in
+    host or HBM, only in VMEM on its way to the output buffer). The selected
+    pages' fp32 scales arrive pre-gathered as one aligned (n_sel, 2, n_g)
+    block per (b, h): a per-lane DMA of a (2, n_g) scale row would slice the
+    lane dimension below its 128-wide tiling. Dequant math matches
+    ``repro.quant.quantizers.dequant_block`` exactly: int -> f32 * scale ->
+    out_dtype."""
     from repro.quant import quantizers as qz
 
     b, h = pl.program_id(0), pl.program_id(1)
@@ -126,25 +132,20 @@ def _quant_kernel(idx_ref, pool_ref, scale_ref, k_ref, v_ref,
         return jnp.clip(idx_ref[b, h, jnp.minimum(i, n_sel - 1)],
                         0, n_pages - 1)
 
-    def dmas(slot, j, i):
+    def dma(slot, j, i):
         src = pool_ref.at[b, page_of(i), h]
-        ssrc = scale_ref.at[b, page_of(i), h]
         if values_only:
             src = src.at[1]                    # V half of the packed block
-            ssrc = ssrc.at[1]
-        return (pltpu.make_async_copy(src, scratch.at[slot, j],
-                                      sems.at[slot, j]),
-                pltpu.make_async_copy(ssrc, sscratch.at[slot, j],
-                                      ssems.at[slot, j]))
+        return pltpu.make_async_copy(src, scratch.at[slot, j],
+                                     sems.at[slot, j])
 
     def start_chunk(slot, c):
-        for j in range(chunk):                 # page + scale DMA per lane
+        for j in range(chunk):                 # one page DMA per lane
             i = c * chunk + j
 
             @pl.when(lane_valid(i))
             def _():
-                for cp in dmas(slot, j, i):
-                    cp.start()
+                dma(slot, j, i).start()
 
     start_chunk(0, 0)
 
@@ -162,13 +163,14 @@ def _quant_kernel(idx_ref, pool_ref, scale_ref, k_ref, v_ref,
 
             @pl.when(valid)
             def _():
-                for cp in dmas(slot, j, i):
-                    cp.wait()
+                dma(slot, j, i).wait()
 
             @pl.when(i < n_sel)
             def _():
-                deq = qz.dequant_block(scratch[slot, j], sscratch[slot, j],
-                                       bits, out_dtype)
+                sc = scale_ref[0, 0, jnp.minimum(i, n_sel - 1)]
+                if values_only:
+                    sc = sc[1]
+                deq = qz.dequant_block(scratch[slot, j], sc, bits, out_dtype)
                 zero = jnp.zeros_like(deq[..., 0, :, :] if not values_only
                                       else deq)
                 if values_only:
@@ -198,19 +200,20 @@ def recall_gather_quant(pool, scales, idx, *, bits, values_only=False,
     n_chunks = -(-n_sel // chunk)
 
     ring = ((2, chunk, p, dp) if values_only else (2, chunk, 2, p, dp))
-    sring = ((2, chunk, n_g) if values_only else (2, chunk, 2, n_g))
+    safe = jnp.clip(idx, 0, n_pages - 1)
+    sel_scales = scales[jnp.arange(B)[:, None, None], safe,
+                        jnp.arange(kv)[None, :, None]]    # (B, kv, n_sel, 2, n_g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, kv),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec((1, 1, n_sel, 2, n_g),
+                               lambda b, h, idx_ref: (b, h, 0, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, 1, n_sel, p, d), lambda b, h, idx_ref: (b, h, 0, 0, 0)),
             pl.BlockSpec((1, 1, n_sel, p, d), lambda b, h, idx_ref: (b, h, 0, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM(ring, pool.dtype),
-                        pltpu.VMEM(sring, scales.dtype),
-                        pltpu.SemaphoreType.DMA((2, chunk)),
                         pltpu.SemaphoreType.DMA((2, chunk))],
     )
     out_shape = [jax.ShapeDtypeStruct((B, kv, n_sel, p, d), out_dtype),
@@ -221,7 +224,8 @@ def recall_gather_quant(pool, scales, idx, *, bits, values_only=False,
         out_dtype=out_dtype)
     k, v = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
-    )(idx, pool, scales)
+        name="recall_gather_quant",
+    )(idx, pool, sel_scales)
     return k, v
 
 
@@ -237,7 +241,7 @@ def recall_gather(pool, idx, *, values_only=False, chunk=None, interpret=True):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, kv),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[
             pl.BlockSpec((1, 1, n_sel, p, d), lambda b, h, idx_ref: (b, h, 0, 0, 0)),
             pl.BlockSpec((1, 1, n_sel, p, d), lambda b, h, idx_ref: (b, h, 0, 0, 0)),
@@ -252,5 +256,6 @@ def recall_gather(pool, idx, *, values_only=False, chunk=None, interpret=True):
         n_chunks=n_chunks, values_only=values_only)
     k, v = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+        name="recall_gather",
     )(idx, pool)
     return k, v

@@ -1,22 +1,26 @@
 """Production meshes. A FUNCTION (not module-level constant) so importing this
-module never touches jax device state."""
+module never touches jax device state. Meshes use Auto axis types: the model
+code relies on GSPMD propagation (``P.UNCONSTRAINED``, implicit broadcasts),
+which Explicit axes — ``jax.make_mesh``'s default — reject."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips with a leading 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Whatever this host offers (tests / examples)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_tp_mesh(tp: int):

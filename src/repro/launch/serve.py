@@ -26,17 +26,19 @@ import json
 import os
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.configs.base import FreeKVConfig
 from repro.data.synthetic import needle_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.obs import Observability, TraceRecorder
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.sampling import SamplerConfig
 
 
-def main():
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b-smoke")
     ap.add_argument("--method", default="freekv")
@@ -47,6 +49,11 @@ def main():
                     help="total requests (default: one per batch slot)")
     ap.add_argument("--budget", type=int, default=128)
     ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument("--n-sink", type=int, default=0,
+                    help="attention-sink tokens kept on device "
+                         "(0 = two pages)")
+    ap.add_argument("--n-window", type=int, default=0,
+                    help="recent-token window kept on device (0 = two pages)")
     ap.add_argument("--tau", type=float, default=0.8)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--scheduler", choices=("continuous", "static"),
@@ -87,6 +94,17 @@ def main():
                     help="disable on-device sampling (synchronous reference "
                          "path: one host round trip per decode step; greedy "
                          "outputs bit-identical either way)")
+    ap.add_argument("--offload", choices=("sim", "host"), default="sim",
+                    help="KV pool placement: host = pinned_host memory with "
+                         "DMA recall (TPU only; fails where the backend has "
+                         "no pinned_host memory), sim = device memory with "
+                         "cost-model transfer accounting")
+    ap.add_argument("--kernels", action="store_true",
+                    help="run decode through the Pallas kernels (paged "
+                         "attention, page scoring, recall gather)")
+    ap.add_argument("--dtype", choices=("float32", "bfloat16"),
+                    default="float32",
+                    help="weight and decode-state dtype")
     ap.add_argument("--kernel-interpret",
                     choices=("auto", "interpret", "compiled"), default="auto",
                     help="Pallas kernel mode: auto = compiled on TPU, "
@@ -94,8 +112,8 @@ def main():
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel shards (KV-head-group sharding "
                          "over a 1-D mesh; bit-identical greedy outputs vs "
-                         "--tp 1). On CPU, forces XLA host devices when "
-                         "needed — set --tp before other jax users import.")
+                         "--tp 1). Under JAX_PLATFORMS=cpu, forces that "
+                         "many XLA host devices.")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="append a JSONL metrics-registry snapshot "
                          "(counters/gauges/histograms) after the run")
@@ -124,20 +142,31 @@ def main():
                     help="HTTP front-end bind address (--serve-http)")
     ap.add_argument("--port", type=int, default=8008,
                     help="HTTP front-end port (--serve-http; 0 = ephemeral)")
-    args = ap.parse_args()
+    return ap
 
-    if args.tp > 1 and "--xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        # must happen before jax initializes its backends
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.tp}")
 
-    cfg = get_config(args.arch)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+def build_engine(args, cfg=None):
+    """The engine a parsed command line describes: ``cfg`` overrides
+    ``--arch`` (e.g. a depth-cut config), weights are random from
+    ``PRNGKey(0)``. Returns (engine, observability plane)."""
+    cfg = cfg or get_config(args.arch)
+    dtype = jnp.dtype(args.dtype)
+    # jitted, so only the weights in ``dtype`` ever reach device memory (run
+    # eagerly, each f32 draw is a buffer of its own); under --tp they are
+    # created replicated on every chip, as tp serving holds them
+    on = None
+    if args.tp > 1:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.launch.mesh import make_tp_mesh
+        on = NamedSharding(make_tp_mesh(args.tp), PartitionSpec())
+    params = jax.jit(lambda key: init_params(cfg, key, dtype),
+                     out_shardings=on)(jax.random.PRNGKey(0))
     fkv = FreeKVConfig(method=args.method, page_size=args.page_size,
-                       budget=args.budget, n_sink=args.page_size * 2,
-                       n_window=args.page_size * 2, tau=args.tau,
+                       budget=args.budget,
+                       n_sink=args.n_sink or args.page_size * 2,
+                       n_window=args.n_window or args.page_size * 2,
+                       tau=args.tau, offload=args.offload,
+                       use_kernels=args.kernels,
                        recall_overlap=not args.no_overlap,
                        kv_quant=args.kv_quant,
                        quant_group_size=args.quant_group_size,
@@ -164,9 +193,33 @@ def main():
                       scheduler=args.scheduler,
                       prefill_bucket=args.prefill_bucket,
                       prefix_cache_tokens=args.prefix_cache_tokens,
-                      tp=args.tp, obs=obs,
+                      state_dtype=dtype, tp=args.tp, obs=obs,
                       slo_ttft_ms=args.slo_ttft_ms,
                       slo_itl_ms=args.slo_itl_ms)
+    return eng, obs
+
+
+def make_requests(args, cfg):
+    """The fixed batch the command line asks for: ``--requests`` needle
+    prompts of ``--context`` tokens, ``--new-tokens`` each."""
+    stream = needle_stream(cfg.vocab_size, args.context, args.page_size)
+    return [Request(uid=i, tokens=next(stream).tokens,
+                    max_new_tokens=args.new_tokens)
+            for i in range(args.requests or args.batch)]
+
+
+def main():
+    args = make_parser().parse_args()
+    if args.tp > 1 and os.environ.get("JAX_PLATFORMS", "") == "cpu" and \
+            "--xla_force_host_platform_device_count" not in \
+            os.environ.get("XLA_FLAGS", ""):
+        # CPU only: give the host platform one device per shard; must
+        # happen before jax initializes its backends
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.tp}")
+    enable_compile_cache()
+    eng, obs = build_engine(args)
 
     if args.serve_http:
         from repro.serving.frontend import (EngineService, HttpFrontend,
@@ -185,11 +238,7 @@ def main():
                 _finish_run(args, em, obs)
         return
 
-    n_req = args.requests or args.batch
-    stream = needle_stream(cfg.vocab_size, args.context, args.page_size)
-    reqs = [Request(uid=i, tokens=next(stream).tokens,
-                    max_new_tokens=args.new_tokens) for i in range(n_req)]
-    for out in eng.generate(reqs):
+    for out in eng.generate(make_requests(args, eng.cfg)):
         steps = max(out.steps, 1)
         print(f"req {out.uid}: {out.tokens}")
         print(f"  prefill {out.prefill_s*1e3:.1f} ms | "

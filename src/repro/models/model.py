@@ -21,14 +21,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs.base import (ArchConfig, FreeKVConfig, ATTN, ATTN_LOCAL,
                                 MAMBA, MLSTM, SLSTM, DENSE, MOE, NONE)
 from repro.models import attention as attn
 from repro.models import layers as L
 from repro.models import moe as moe_mod
 from repro.models import ssm, xlstm
+from repro.core import offload
 from repro.core.retrieval import make_retriever, StreamingRetriever
 
 
@@ -148,21 +149,6 @@ def _apply_ffn(cfg, lk, p, x, mesh):
 
 
 ROW_PARALLEL_KEYS = ("down", "wo", "wd", "out_proj", "x_proj")
-
-
-# ``jax.lax.optimization_barrier`` has no differentiation rule on the
-# jax-0.4.x line (one landed upstream later). The barrier is semantically the
-# identity, so give it one: identity JVP (and therefore identity transpose),
-# keeping the primal barrier in the saved-activation path under remat while
-# letting gradients flow straight through.
-@jax.custom_jvp
-def _opt_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-@_opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    return _opt_barrier(primals[0]), tangents[0]
 
 
 def _gather_for_compute(cfg, mesh, lp):
@@ -373,7 +359,7 @@ def forward_train(cfg: ArchConfig, params, batch, mesh=None, remat=True):
             bspec = ba if x.shape[0] % max(nb, 1) == 0 else None
             x = jax.lax.with_sharding_constraint(
                 x, NamedSharding(mesh, P(bspec, "model", None)))
-        return _opt_barrier(x)
+        return jax.lax.optimization_barrier(x)
 
     def scan_body(x, lps):
         return body(_shard_saved(x), lps)
@@ -735,15 +721,10 @@ def serve_step(cfg: ArchConfig, fkv: FreeKVConfig, params, state, tokens,
         lps, i = xs
         new_states = []
         for pos_i, lk in enumerate(cfg.pattern):
-            st_i = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-                states[pos_i])
             x, st, q_proxy, info = _apply_layer_decode(
-                cfg, fkv, lk, pat_r[pos_i], lps[pos_i], x, pos, st_i,
-                cmesh, q_proxy)
-            new_states.append(jax.tree.map(
-                lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                    a, n.astype(a.dtype), i, 0), states[pos_i], st))
+                cfg, fkv, lk, pat_r[pos_i], lps[pos_i], x, pos,
+                offload.layer_state(states[pos_i], i), cmesh, q_proxy)
+            new_states.append(offload.layer_writeback(states[pos_i], st, i))
             s = _info_stats(info if lk[0] == ATTN else None, B)
             acc = {k: acc[k] + s[k] for k in acc}
         return (x, q_proxy, acc, tuple(new_states)), None
@@ -952,15 +933,11 @@ def serve_step_verify(cfg: ArchConfig, fkv: FreeKVConfig, params, state,
         lps, i = xs
         undos = []
         for pos_i, lk in enumerate(cfg.pattern):
-            st_i = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-                states[pos_i])
             x, st, q_proxy, rows, undo = _apply_layer_verify(
-                cfg, fkv, lk, pat_r[pos_i], lps[pos_i], x, pos, st_i,
-                cmesh, q_proxy)
-            states = states[:pos_i] + (jax.tree.map(
-                lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                    a, n.astype(a.dtype), i, 0), states[pos_i], st),) \
+                cfg, fkv, lk, pat_r[pos_i], lps[pos_i], x, pos,
+                offload.layer_state(states[pos_i], i), cmesh, q_proxy)
+            states = states[:pos_i] + (
+                offload.layer_writeback(states[pos_i], st, i),) \
                 + states[pos_i + 1:]
             undos.append(undo)
             acc = {k: acc[k] + rows[k] for k in acc}
@@ -988,14 +965,11 @@ def _rewind_state(cfg, state, undo_info, m, last_row):
     def rewind_body(states, xs):
         undos_i, i = xs
         for pos_i in range(len(cfg.pattern)):
-            st_i = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-                states[pos_i])
-            st2 = _rewind_layer(pat_r[pos_i], st_i, keep_len, undos_i[pos_i],
-                                last_row, m)
-            states = states[:pos_i] + (jax.tree.map(
-                lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                    a, n.astype(a.dtype), i, 0), states[pos_i], st2),) \
+            st2 = _rewind_layer(pat_r[pos_i],
+                                offload.layer_state(states[pos_i], i),
+                                keep_len, undos_i[pos_i], last_row, m)
+            states = states[:pos_i] + (
+                offload.layer_writeback(states[pos_i], st2, i),) \
                 + states[pos_i + 1:]
         return states, None
 

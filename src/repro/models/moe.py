@@ -15,8 +15,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.models.layers import act_fn, dense_init, mlp_init, apply_mlp
 

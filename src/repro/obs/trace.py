@@ -29,7 +29,6 @@ The same span names are exported as ``jax.named_scope`` annotations via
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import time
 from typing import Dict, List, Optional
@@ -73,10 +72,7 @@ def annotate(name: str):
     """``jax.named_scope`` on the shared span names — free at runtime
     (HLO metadata only), and it makes ``jax.profiler`` traces line up
     with the host-side Perfetto spans."""
-    try:
-        return jax.named_scope(name)
-    except Exception:                      # pragma: no cover - old jax
-        return contextlib.nullcontext()
+    return jax.named_scope(name)
 
 
 class TraceRecorder:

@@ -15,6 +15,8 @@ compression ratios reported by ``benchmarks/quant_quality.py`` include them.
 """
 from __future__ import annotations
 
+import numpy as np
+
 from repro.quant.quantizers import effective_group, quant_bits
 
 # Nominal dequant throughput (elements/s) for the cost-model estimate of
@@ -64,6 +66,9 @@ def pool_bytes_detail(state, d_head: int, dense_itemsize: int = 2) -> dict:
         key = str(getattr(path[-1], "key", path[-1]))
         if key == "pool" and hasattr(leaf, "nbytes"):
             acc["payload"] += leaf.nbytes
+            if leaf.dtype == np.uint32:       # host word format: fp, unpacked
+                acc["dense"] += leaf.nbytes
+                return leaf
             n_elems = leaf.size // leaf.shape[-1] * d_head
             acc["dense"] += n_elems * dense_itemsize
         elif key == "pool_scale" and hasattr(leaf, "nbytes"):

@@ -503,8 +503,9 @@ class ServeEngine:
                                             seed + i))
         em = EngineMetrics(num_slots=self.batch_size, scheduler="static",
                            tp=self.tp, sample_on_device=False)
-        from repro.core.offload import host_offload_active
-        em.transfer_is_dma = host_offload_active(self.fkv)
+        # the static path never host-places its pool (SlotPool does that
+        # for the continuous scheduler), so its transfers are simulated
+        em.transfer_is_dma = False
         em.page_block_bytes = self.page_block_bytes
         self._apply_quant_metrics(em)
         em.wall_s = time.perf_counter() - t0

@@ -19,6 +19,7 @@ The slot index is a traced scalar, so one compiled insert serves every slot.
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Set
 
 import jax
@@ -28,16 +29,22 @@ from repro.core import paging
 from repro.models.model import init_decode_state
 
 
-def _splice(dst, src, slot):
+def _splice(dst, src, slot, interpret=None, host_shardings=None):
+    """``host_shardings`` (the ``dst`` tree, a NamedSharding at each
+    host-resident leaf under a mesh, else None) lets the host-row DMA run
+    per shard."""
+    def put(group, axis):
+        shardings = (host_shardings[group] if host_shardings is not None
+                     else [jax.tree.map(lambda _: None, d) for d in dst[group]])
+        return tuple(
+            jax.tree.map(lambda a, b, sh: paging.slot_write_leaf(
+                a, b, slot, axis=axis, interpret=interpret, sharding=sh),
+                d, s, h)
+            for d, s, h in zip(dst[group], src[group], shardings))
+
     out = dict(dst)
-    out["prelude"] = tuple(
-        jax.tree.map(lambda a, b: paging.slot_write_leaf(a, b, slot, axis=0),
-                     d, s)
-        for d, s in zip(dst["prelude"], src["prelude"]))
-    out["pattern"] = tuple(
-        jax.tree.map(lambda a, b: paging.slot_write_leaf(a, b, slot, axis=1),
-                     d, s)
-        for d, s in zip(dst["pattern"], src["pattern"]))
+    out["prelude"] = put("prelude", 0)
+    out["pattern"] = put("pattern", 1)
     out["pos"] = paging.slot_write_leaf(dst["pos"], src["pos"], slot, axis=0)
     # any extra top-level lane (e.g. the spec-decode draft_tab) batches on
     # axis 0, like pos
@@ -70,9 +77,9 @@ class SlotPool:
     under the sharding ``sharding/rules.decode_state_shardings`` assigns —
     KV-head dim over 'model' for pools/summaries/rings/selection buffers —
     so the shard_map'ped decode step consumes its inputs without any
-    resharding, and per-slot splices stay slot-local per shard. The host
-    pool leaves (+ quant scales) additionally move to host memory when
-    ``fkv.offload == 'host'`` (``core/offload.place_decode_state``)."""
+    resharding, and per-slot splices stay slot-local per shard. Under
+    ``fkv.offload == 'host'`` the pool leaves are created in pinned_host
+    memory, in the host word format (``core/offload.init_decode_state``)."""
 
     def __init__(self, cfg, fkv, num_slots: int, max_len: int,
                  state_dtype=jnp.float32, mesh=None):
@@ -92,28 +99,38 @@ class SlotPool:
             return jax.jit(fn, out_shardings=shardings)
 
         self._init_full = _mk_init(num_slots)
-        self._template = self._place(_mk_init(1)())
+        # the reset template stays in device memory even under the host
+        # tier: a splice writes host rows by DMA from HBM
+        self._template = _mk_init(1)()
         # the destination state is DONATED: a splice updates the pool in
         # place instead of copying every leaf (the pool dominates the state
         # footprint). The B=1 source (arg 1) is NOT donated — the reset
         # template is spliced in repeatedly. Callers reassign ``self.state``
         # immediately, so the consumed buffers are never read again.
-        self._splice = jax.jit(_splice, donate_argnums=(0,))
+        from repro.core.offload import is_host
+        from repro.kernels.ops import resolve_interpret
         self._extract = jax.jit(_extract)
-        self.state = self._place(self._init_full())
+        self.state = self._fresh_state()
+        host_shardings = None if mesh is None else {
+            k: jax.tree.map(lambda a: a.sharding if is_host(a) else None,
+                            self.state[k]) for k in ("prelude", "pattern")}
+        self._splice = jax.jit(
+            functools.partial(_splice, interpret=resolve_interpret(fkv),
+                              host_shardings=host_shardings),
+            donate_argnums=(0,))
         self._free: List[int] = list(range(num_slots - 1, -1, -1))
         self._dirty: Set[int] = set()
         self.owner: List[Optional[int]] = [None] * num_slots
         self.allocs = 0
 
-    def _place(self, state):
-        """Move pool leaves (+ quant scales) to host memory under
-        ``fkv.offload == 'host'`` — sharding-preserving under a mesh (each
-        shard's KV-head-group slice is host-resident on its own device).
-        No-op otherwise."""
-        from repro.core.offload import place_decode_state
-        return place_decode_state(state, self.fkv, mesh=self.mesh,
-                                  cfg=self.cfg)
+    def _fresh_state(self):
+        """The all-slots zero state; under ``fkv.offload == 'host'`` its pool
+        leaves are created in host memory directly —
+        sharding-preserving under a mesh (each shard's KV-head-group slice
+        is host-resident on its own device)."""
+        from repro.core.offload import init_decode_state as init_placed
+        return init_placed(self._init_full, self.fkv, mesh=self.mesh,
+                           cfg=self.cfg)
 
     # -- bookkeeping ---------------------------------------------------
     @property
@@ -197,7 +214,7 @@ class SlotPool:
                                   jnp.int32(slot))
 
     def reset_all(self):
-        self.state = self._place(self._init_full())
+        self.state = self._fresh_state()
         self._free = list(range(self.num_slots - 1, -1, -1))
         self._dirty = set()
         self.owner = [None] * self.num_slots

@@ -46,6 +46,25 @@ def test_paged_attention_softcap():
     np.testing.assert_allclose(np.asarray(o), np.asarray(oref), atol=2e-5)
 
 
+@pytest.mark.parametrize("N", [8, 5])
+def test_paged_attention_blocked(N):
+    """A resident set longer than ``max_block`` streams in blocks with an
+    online softmax; N=5 leaves a ragged last block, masked by token index."""
+    from repro.kernels.paged_attention import paged_attention
+    B, kv, G, p, d = 2, 2, 4, 32, 128
+    q = jax.random.normal(KEY, (B, kv, G, d))
+    kp = jax.random.normal(jax.random.fold_in(KEY, 1), (B, kv, N, p, d))
+    vp = jax.random.normal(jax.random.fold_in(KEY, 2), (B, kv, N, p, d))
+    pos = jax.random.randint(jax.random.fold_in(KEY, 3), (B, kv, N, p), -1,
+                             N * p)
+    cur = jnp.array([N * p, N * p // 2], jnp.int32)
+    o = paged_attention(q, kp, vp, pos, cur, scale=d ** -0.5,
+                        interpret=True, max_block=128)
+    oref = ref.paged_attention_ref(q, kp, vp, pos, cur, d ** -0.5)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(oref), **_tol(
+        jnp.float32))
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("B,T,kv,d,p", [
     (1, 64, 1, 128, 8), (2, 128, 3, 128, 32), (2, 96, 2, 64, 16),
@@ -112,3 +131,57 @@ def test_flash_prefill_window():
     o = ops.flash_prefill(q, k, v, scale=0.125, window=64, blq=64, blk=64)
     oref = ref.flash_prefill_ref(q, k, v, 0.125, window=64)
     np.testing.assert_allclose(np.asarray(o), np.asarray(oref), atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("stacked", [False, True])
+def test_recall_host_matches_ref(dtype, stacked):
+    """The host-pool DMA recall (interpret mode, device-resident words)
+    returns exactly ``recall_gather_ref``'s pages, zeros for -1 lanes."""
+    from repro.core import offload, paging
+    B, n_pages, kv, p, d, n_sel, L = 2, 6, 2, 32, 128, 5, 3
+    pools = jax.random.normal(KEY, (L, B, n_pages, kv, 2, p, d), dtype)
+    idx = jax.random.randint(jax.random.fold_in(KEY, 1), (B, kv, n_sel), -1,
+                             n_pages)
+    layer = 1
+    words = paging.hnd_to_words(pools if stacked else pools[layer])
+    view = offload.HostPool(words, jnp.int32(layer) if stacked else None,
+                            jnp.dtype(dtype), p, d, True)
+    k, v = offload.recall_host(view, idx)
+    kr, vr = ref.recall_gather_ref(pools[layer], idx)
+    np.testing.assert_array_equal(np.asarray(k, np.float32),
+                                  np.asarray(kr, np.float32))
+    np.testing.assert_array_equal(np.asarray(v, np.float32),
+                                  np.asarray(vr, np.float32))
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+def test_write_host_matches_masked_scatter(stacked):
+    """Page offload by DMA writes exactly the rows whose page completed."""
+    from repro.core import offload, paging
+    from repro.kernels.host_pages import write_slot
+    B, n_pages, kv, p, d, L = 3, 5, 2, 32, 128, 2
+    pools = jax.random.normal(KEY, (L, B, n_pages, kv, 2, p, d), jnp.bfloat16)
+    blk = jax.random.normal(jax.random.fold_in(KEY, 1), (B, kv, 2, p, d),
+                            jnp.bfloat16)
+    tgt = jnp.array([4, 0, 2], jnp.int32)
+    done = jnp.array([True, False, True])
+    layer = 1
+    words = paging.hnd_to_words(pools if stacked else pools[layer])
+    out = offload.write_page(words, blk, tgt, done,
+                             layer=jnp.int32(layer) if stacked else None,
+                             interpret=True)
+    want = pools[layer].at[jnp.arange(B), tgt].set(jnp.where(
+        done[:, None, None, None, None], blk,
+        pools[layer][jnp.arange(B), tgt]))
+    got = out[layer] if stacked else out
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(paging.hnd_to_words(want)))
+    # slot splice: a batch-1 row lands in row 1 of every layer
+    src = jax.random.normal(jax.random.fold_in(KEY, 2),
+                            (L, 1, n_pages, kv, 2, p, d), jnp.bfloat16)
+    spliced = write_slot(paging.hnd_to_words(pools), paging.hnd_to_words(src),
+                         jnp.int32(1), axis=1, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(spliced),
+        np.asarray(paging.hnd_to_words(pools.at[:, 1].set(src[:, 0]))))

@@ -188,13 +188,15 @@ def test_top_p_dynamic_budget(smoke_cfg):
 
 
 def test_host_offload_placement(smoke_cfg, small_fkv):
-    """offload='host' places the pool in pinned_host memory (when supported)
-    and decode still runs (XLA inserts the transfers)."""
+    """offload='host' places the pool in pinned_host memory, in the host word
+    format, and decode still runs where the backend can compute on it."""
     import dataclasses
     import jax
     import jax.numpy as jnp
-    from repro.core.offload import place_decode_state, pool_bytes
-    fkv = dataclasses.replace(small_fkv, offload="host")
+    from repro.core.offload import place_decode_state, pool_bytes, pool_on_host
+    # 32-token pages: the host pool's DMA unit is a whole 16 KiB block
+    fkv = dataclasses.replace(small_fkv, offload="host", page_size=32,
+                              budget=128, n_sink=32, n_window=32)
     r = make_retriever(smoke_cfg, fkv)
     st = r.init_state(2, 128, jnp.float32)
     k = jax.random.normal(KEY, (2, 96, smoke_cfg.n_kv_heads, smoke_cfg.d_head))
@@ -203,9 +205,8 @@ def test_host_offload_placement(smoke_cfg, small_fkv):
                                (2, smoke_cfg.n_heads, smoke_cfg.d_head))
     st = r.prefill(st, k, v, q_last)
     st = place_decode_state(st, fkv)
-    from repro.core.offload import host_memory_kind
-    kinds = {getattr(st["pool"].sharding, "memory_kind", None)}
-    assert kinds <= {host_memory_kind(), None}
+    assert st["pool"].sharding.memory_kind == "pinned_host"
+    assert pool_on_host(st)
     assert pool_bytes(st) > 0
     q, kn, vn = _decode_inputs(smoke_cfg, 2, 0)
     try:

@@ -52,3 +52,40 @@ def test_method_consistency_full_vs_freekv_bigbudget():
         outs[method] = eng.generate(
             [Request(uid=0, tokens=prompt, max_new_tokens=8)])[0].tokens
     assert outs["full"] == outs["freekv"]
+
+
+def test_serve_cli_builds_kernel_bf16_engine():
+    """``launch/serve``'s shared factory (the path ``chip_smoke.py`` drives)
+    turns on the Pallas kernels and a bf16 model/state from the CLI."""
+    from repro.launch import serve
+    args = serve.make_parser().parse_args(
+        ["--arch", "smollm-360m-smoke", "--context", "64", "--new-tokens",
+         "4", "--batch", "2", "--requests", "3", "--page-size", "8",
+         "--budget", "48", "--kernels", "--dtype", "bfloat16", "--no-obs"])
+    eng, _ = serve.build_engine(args)
+    assert eng.fkv.use_kernels and eng.fkv.offload == "sim"
+    assert {leaf.dtype for leaf in jax.tree.leaves(eng.params)} == {
+        jnp.dtype(jnp.bfloat16)}
+    outs = eng.generate(serve.make_requests(args, eng.cfg))
+    assert [len(o.tokens) for o in outs] == [4, 4, 4]
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The environment's cache directory wins and nothing else is set;
+    otherwise the cache sits at one fixed, git-ignored path in the
+    checkout."""
+    from pathlib import Path
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        root = Path(__file__).resolve().parents[1]
+        assert got == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

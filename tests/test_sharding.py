@@ -11,15 +11,8 @@ from repro.configs.base import FreeKVConfig, SHAPES
 from repro.models.model import init_decode_state, init_params
 from repro.sharding import rules
 
-def _abstract_mesh(shape, names):
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:   # jax <= 0.4.x: single shape_tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(names, shape)))
-
-
-MESHES = [_abstract_mesh((16, 16), ("data", "model")),
-          _abstract_mesh((2, 16, 16), ("pod", "data", "model"))]
+MESHES = [AbstractMesh((16, 16), ("data", "model")),
+          AbstractMesh((2, 16, 16), ("pod", "data", "model"))]
 FKV = FreeKVConfig(method="freekv", page_size=32, budget=2048, n_sink=512,
                    n_window=512, pool_pad_pages=512)
 
