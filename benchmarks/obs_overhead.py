@@ -7,7 +7,7 @@ host-sync-free loop (``sync_interval=8``):
   the engine's bookkeeping and always run), no histograms, no trace.
 * **obs on (full)** — per-step latency + speculation-quality histograms
   AND the Chrome-trace/Perfetto recorder capturing the request lifecycle,
-  decode windows/steps and recall-pipeline spans.
+  the engine loop's spans and the per-window page / speculation counts.
 
 Gated results (``tools/check_bench.py``):
 

@@ -5,9 +5,10 @@ Three cooperating pieces:
 * :mod:`repro.obs.registry` — streaming metrics registry (counters,
   gauges, fixed-bucket histograms with p50/p90/p99); the single
   aggregation substrate behind ``serving/metrics.EngineMetrics``.
-* :mod:`repro.obs.trace` — Chrome-trace/Perfetto span recorder for the
-  request lifecycle and the recall pipeline, plus ``jax.named_scope``
-  annotation hooks on the same span names.
+* :mod:`repro.obs.trace` — the span vocabulary: ``span`` writes the
+  engine loop's host spans into a running ``jax.profiler`` trace and, when
+  enabled, into the Chrome-trace/Perfetto recorder beside the request
+  lifecycle; ``annotate`` puts the same names on the jitted step.
 * speculation-quality telemetry — per-step speculative page-hit rate,
   corrected-head count, and selection churn, accumulated **on device**
   inside ``decode_window``'s ``(k, B)`` stat blocks and pulled only at
@@ -44,7 +45,6 @@ from repro.obs.timeseries import (  # noqa: F401
 )
 from repro.obs.trace import (  # noqa: F401
     SPAN_ATTN_COMPUTE,
-    SPAN_DECODE_STEP,
     SPAN_DECODE_WINDOW,
     SPAN_RECALL_CORRECTION,
     SPAN_RECALL_REUSE,
@@ -57,6 +57,7 @@ from repro.obs.trace import (  # noqa: F401
     SPAN_REQUEST_QUEUED,
     TraceRecorder,
     annotate,
+    span,
     validate_chrome_trace,
 )
 

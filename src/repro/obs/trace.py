@@ -1,31 +1,31 @@
-"""Trace spans: Chrome-trace / Perfetto JSON for the serving pipeline.
+"""Trace spans: one vocabulary for the profiler's trace and Perfetto JSON.
 
-``TraceRecorder`` buffers Trace Event Format events (the JSON Perfetto and
-``chrome://tracing`` load natively) and writes them with
-:meth:`TraceRecorder.write`:
+:class:`span` is the one way the program opens a span. It always opens a
+``jax.profiler.TraceAnnotation`` (a TraceMe): while a ``jax.profiler``
+trace runs, the span lands in it on the host thread that opened it, on the
+same clock as the device's operations; with no profile running it costs
+a few microseconds of host time. When a :class:`TraceRecorder` is enabled the span is
+also buffered as a Chrome ``X`` event timed by ``time.perf_counter`` at
+the same entry and exit, so ``--trace-out`` exports exactly the spans the
+profiler sees. Spans are opened where the work happens, on the engine
+thread (``serving/scheduler.py``), a fixed handful per decode window: none
+per token, slot or layer.
+
+Besides spans, :class:`TraceRecorder` writes (Trace Event Format, which
+Perfetto and ``chrome://tracing`` load):
 
 * request-lifecycle spans — one Perfetto *thread* per request uid with
   ``request/queued`` -> ``request/prefill`` -> ``request/decode`` spans
-  and a ``request/done`` instant (scheduler emits these at finish time
-  from the ``RequestMetrics`` timestamps, so tracing adds no bookkeeping
-  to the hot path);
-* engine spans — ``engine/decode_window`` per host sync with the fused
-  step count / pulled bytes in ``args``, split into per-step
-  ``engine/decode_step`` spans on the engine track;
-* recall-pipeline spans — per-step ``recall/topup`` (blocking correction
-  top-up) on the engine track and ``recall/staged`` (overlapped
-  speculative stage) on a separate DMA track, so the hidden-fraction
-  claim is visually auditable as overlap. In simulation the DMA span
-  durations are **modeled** from block counts at ``MODEL_LINK_BW``
-  (mirrors ``benchmarks/_common.HwModel.host_link_bw``) — the event
-  ``args`` carry the exact byte counts;
-* counter tracks — ``speculation/hit_rate`` and
-  ``speculation/correction_rate`` sampled once per sync boundary, giving
-  the paper's accuracy-side signal as a timeline.
+  and a ``request/done`` instant, emitted at finish from the
+  ``RequestMetrics`` timestamps (no bookkeeping on the hot path);
+* counts pulled at each sync boundary, stamped at the window's end — the
+  ``recall/pages`` counter track (sync / async / reused pages), the
+  ``speculation`` counter track (hit and correction rates) and one
+  ``engine/spec_verify`` instant per drafted verify iteration
+  (proposed / accepted / committed tokens). They are counts, not times.
 
-The same span names are exported as ``jax.named_scope`` annotations via
-:func:`annotate` (used inside the jitted retrieval path), so a real
-``jax.profiler`` trace lines up with the host-side spans by name.
+Inside the jitted step, :func:`annotate` puts the recall and attention
+names on the HLO as ``jax.named_scope`` metadata (free at run time).
 """
 from __future__ import annotations
 
@@ -35,25 +35,40 @@ from typing import Dict, List, Optional
 
 import jax
 
-# modeled host<->device link bandwidth for simulated DMA span durations;
-# keep in sync with benchmarks/_common.HwModel.host_link_bw
-MODEL_LINK_BW = 20e9
-
 # --- span taxonomy (docs/observability.md) -----------------------------
 SPAN_REQUEST_QUEUED = "request/queued"
 SPAN_REQUEST_PREFILL = "request/prefill"
 SPAN_REQUEST_DECODE = "request/decode"
 SPAN_REQUEST_DONE = "request/done"
+# engine host loop (scheduler.py), host-sync-free path: one window is
+# lanes -> dispatch -> sync_wait -> pull -> apply
 SPAN_DECODE_WINDOW = "engine/decode_window"
-SPAN_DECODE_STEP = "engine/decode_step"
-# one drafted-block verify iteration (speculative decoding): args carry the
-# live-slot count plus proposed/accepted/committed token counts, so the
-# accepted-tokens-per-target-step distribution is readable off the trace
-SPAN_SPEC_VERIFY = "engine/spec_verify"
+SPAN_LANES = "engine/lanes"
+SPAN_DISPATCH = "engine/dispatch"
+SPAN_SYNC_WAIT = "engine/sync_wait"
+SPAN_PULL = "engine/pull"
+SPAN_APPLY = "engine/apply"
+# synchronous reference path: one step, then engine/sync_wait
+SPAN_STEP = "engine/step"
+SPAN_FRONTEND_POLL = "frontend/poll"
+SPAN_FLUSH_RESETS = "pool/flush_resets"
+SPAN_IDLE_WAIT = "engine/idle_wait"
+# admission: sched/admit > engine/prefill, pool/splice, engine/first_token
+SPAN_SCHED_ADMIT = "sched/admit"
+SPAN_PREFILL = "engine/prefill"
+SPAN_SPLICE = "pool/splice"
+SPAN_FIRST_TOKEN = "engine/first_token"
 SPAN_PREFILL_CHUNK = "engine/prefill_chunk"
 SPAN_SCHED_PREEMPT = "sched/preempt"
 SPAN_SCHED_RESUME = "sched/resume"
 SPAN_SCHED_CANCEL = "sched/cancel"
+# instant per drafted verify iteration: live-slot count plus proposed /
+# accepted / committed token counts
+SPAN_SPEC_VERIFY = "engine/spec_verify"
+# counter tracks, sampled once per sync boundary
+COUNTER_RECALL_PAGES = "recall/pages"
+COUNTER_SPECULATION = "speculation"
+# named scopes inside the jitted step (annotate)
 SPAN_RECALL_SELECT = "recall/select"
 SPAN_RECALL_CORRECTION = "recall/correction"
 SPAN_RECALL_TOPUP = "recall/topup"
@@ -65,14 +80,50 @@ SPAN_ATTN_COMPUTE = "attn/compute"
 PID_ENGINE = 1
 PID_REQUESTS = 2
 TID_ENGINE = 1
-TID_DMA = 2
 
 
 def annotate(name: str):
     """``jax.named_scope`` on the shared span names — free at runtime
-    (HLO metadata only), and it makes ``jax.profiler`` traces line up
-    with the host-side Perfetto spans."""
+    (HLO metadata only); the profiler shows it on the device's ops."""
     return jax.named_scope(name)
+
+
+class span:
+    """A host span around real work: ``with span(name, recorder, **args)``.
+
+    Always a ``jax.profiler.TraceAnnotation`` (recorded only while a
+    profile runs); also a Chrome ``X`` event when ``recorder`` is enabled.
+    ``set(**args)`` attaches values known only at the end (counts, bytes)
+    to both."""
+
+    __slots__ = ("_name", "_rec", "_args", "_me", "_t")
+
+    def __init__(self, name: str, recorder: Optional["TraceRecorder"] = None,
+                 **args):
+        self._name = name
+        self._rec = recorder if recorder is not None and recorder.enabled \
+            else None
+        self._args = args
+        self._me = jax.profiler.TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "span":
+        self._me.__enter__()
+        if self._rec is not None:
+            self._t = time.perf_counter()
+        return self
+
+    def set(self, **args) -> None:
+        self._me.set_metadata(**args)
+        if self._rec is not None:
+            self._args.update(args)
+
+    def __exit__(self, *exc) -> None:
+        if self._rec is not None:
+            t = self._t
+            self._rec.complete(self._name, t - self._rec.origin,
+                               time.perf_counter() - t,
+                               args=self._args or None)
+        self._me.__exit__(*exc)
 
 
 class TraceRecorder:
@@ -82,19 +133,18 @@ class TraceRecorder:
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self.events: List[dict] = []
-        self._origin: Optional[float] = None
+        self.origin = time.perf_counter()
         self._names: Dict[tuple, str] = {}
         if enabled:
             self._meta(PID_ENGINE, None, "process_name", "serve-engine")
             self._meta(PID_ENGINE, TID_ENGINE, "thread_name", "decode")
-            self._meta(PID_ENGINE, TID_DMA, "thread_name", "recall-dma")
             self._meta(PID_REQUESTS, None, "process_name", "requests")
 
     # -- clock ---------------------------------------------------------
     def set_origin(self, t: Optional[float] = None) -> None:
-        """Anchor ts=0; scheduler calls this with its run-start time so
+        """Anchor ts=0; the scheduler calls this with its run-start time so
         span timestamps equal the RequestMetrics timeline."""
-        self._origin = time.perf_counter() if t is None else t
+        self.origin = time.perf_counter() if t is None else t
 
     def _us(self, t_s: float) -> float:
         return t_s * 1e6
@@ -168,34 +218,6 @@ class TraceRecorder:
         if rm.finish_t is not None:
             self.instant(SPAN_REQUEST_DONE, rm.finish_t, pid=PID_REQUESTS,
                          tid=uid, args={"uid": uid})
-
-    def recall_step(self, ts_s: float, dur_s: float, *, sync_pages: float,
-                    async_pages: float, reused_pages: float,
-                    page_block_bytes: float) -> None:
-        """Per-step recall stage spans: the blocking top-up lives on the
-        decode track (it is on the critical path); the speculative stage
-        for the *next* step runs on the DMA track in parallel with the
-        step's compute. Durations are modeled (bytes / MODEL_LINK_BW) in
-        simulation; args carry the exact page/byte counts."""
-        if not self.enabled:
-            return
-        if sync_pages > 0:
-            b = sync_pages * page_block_bytes
-            self.complete(SPAN_RECALL_TOPUP, ts_s,
-                          min(b / MODEL_LINK_BW, dur_s),
-                          tid=TID_ENGINE,
-                          args={"pages": sync_pages, "bytes": b,
-                                "modeled": True})
-        if async_pages > 0:
-            b = async_pages * page_block_bytes
-            self.complete(SPAN_RECALL_STAGED, ts_s,
-                          min(b / MODEL_LINK_BW, dur_s),
-                          tid=TID_DMA,
-                          args={"pages": async_pages, "bytes": b,
-                                "modeled": True, "hidden": True})
-        if reused_pages > 0:
-            self.instant(SPAN_RECALL_REUSE, ts_s, tid=TID_DMA,
-                         args={"pages": reused_pages})
 
     # -- export ----------------------------------------------------------
     def chrome_trace(self) -> dict:

@@ -74,10 +74,15 @@ import numpy as np
 from repro.core.recall_pipeline import RecallFlightTracker
 from repro.models.model import DECODE_STAT_KEYS as _STAT_KEYS
 from repro.obs import Observability
-from repro.obs.trace import (SPAN_DECODE_STEP, SPAN_DECODE_WINDOW,
-                             SPAN_PREFILL_CHUNK, SPAN_SCHED_CANCEL,
+from repro.obs.trace import (COUNTER_RECALL_PAGES, COUNTER_SPECULATION,
+                             SPAN_APPLY, SPAN_DECODE_WINDOW, SPAN_DISPATCH,
+                             SPAN_FIRST_TOKEN, SPAN_FLUSH_RESETS,
+                             SPAN_FRONTEND_POLL, SPAN_IDLE_WAIT, SPAN_LANES,
+                             SPAN_PREFILL, SPAN_PREFILL_CHUNK, SPAN_PULL,
+                             SPAN_SCHED_ADMIT, SPAN_SCHED_CANCEL,
                              SPAN_SCHED_PREEMPT, SPAN_SCHED_RESUME,
-                             SPAN_SPEC_VERIFY)
+                             SPAN_SPEC_VERIFY, SPAN_SPLICE, SPAN_STEP,
+                             SPAN_SYNC_WAIT, span)
 from repro.serving.metrics import EngineMetrics, RequestMetrics
 from repro.serving.sampling import request_key
 
@@ -209,10 +214,11 @@ class ContinuousScheduler:
                      and hasattr(backend, "decode_window"))
         obs = getattr(backend, "obs", None) or Observability.off()
         self._obs, self._trace = obs, obs.trace
+        rec = self._trace
         board = obs.timeseries          # None -> no windowed aggregation
-        self._page_block_bytes = backend.page_block_bytes
         t0 = time.perf_counter()
         self._t0 = t0
+        rec.set_origin(t0)
         now = lambda: time.perf_counter() - t0  # noqa: E731
         abst = lambda rel: t0 + rel             # noqa: E731  (board clock)
 
@@ -336,11 +342,12 @@ class ContinuousScheduler:
                        interpolated=False):
             """Host bookkeeping for ONE decode step: telemetry, token
             append, finish detection. Shared by both dispatch modes.
-            ``ts`` (run-relative seconds) anchors the step's trace spans;
-            everything recorded here came out of the sync-boundary stat
-            pull — no extra host traffic. ``interpolated`` marks per-token
-            timestamps subdivided out of one dispatch (window mode and
-            speculative verify rows) for downstream event consumers."""
+            ``ts`` (run-relative seconds) is the step's start, from which
+            its tokens are stamped; everything recorded here came out of
+            the sync-boundary stat pull — no extra host traffic.
+            ``interpolated`` marks per-token timestamps subdivided out of
+            one dispatch (window mode and speculative verify rows) for
+            downstream event consumers."""
             em.record_step(len(live_slots))
             for k in _PAGE_KEYS + ("corrected_heads", "kv_head_steps"):
                 src = {"corrected_heads": "corrected",
@@ -360,8 +367,6 @@ class ContinuousScheduler:
                         float(stats_np["churn_pages"][s]),
                         float(stats_np["corrected"][s]),
                         float(stats_np["kv_heads"][s]))
-            if ts is not None and self._trace.enabled:
-                self._trace_step(stats_np, live_slots, ts, dt)
             tok_t = (ts + dt) if ts is not None else now()
             if board is not None:
                 board.observe("decode_step_s", dt, abst(tok_t))
@@ -405,7 +410,9 @@ class ContinuousScheduler:
 
         def begin_decode(tr, slot, logits1, rkey):
             """First token out of a completed prefill -> decode lane."""
-            tok = int(np.asarray(backend.sample_slot(logits1, rkey, 0))[0])
+            with span(SPAN_FIRST_TOKEN, rec):
+                tok = int(np.asarray(backend.sample_slot(logits1, rkey,
+                                                         0))[0])
             tr.metrics.first_token_t = now()
             tr.last_tok_t = tr.metrics.first_token_t
             tr.tokens.append(tok)
@@ -431,9 +438,11 @@ class ContinuousScheduler:
             """Swap a preempted request's parked KV back into a fresh slot;
             its lane (current token, key stream, count) rebuilds from host
             bookkeeping, so generation continues bit-identically."""
-            slot = pool.alloc(tr.req.uid)
-            nbytes = _state_nbytes(tr.host_state)
-            pool.swap_in(tr.host_state, slot)
+            with span(SPAN_SCHED_RESUME, rec, uid=tr.req.uid) as sp:
+                slot = pool.alloc(tr.req.uid)
+                nbytes = _state_nbytes(tr.host_state)
+                pool.swap_in(tr.host_state, slot)
+                sp.set(slot=slot, bytes=nbytes)
             tr.host_state = None
             flight.restore(slot, tr.flight_pages)
             tr.flight_pages = 0.0
@@ -448,12 +457,13 @@ class ContinuousScheduler:
             em.swap_in_bytes += nbytes
             if board is not None:
                 board.event("swap_bytes", nbytes, abst(now()))
-            self._trace.instant(SPAN_SCHED_RESUME, now(),
-                                args={"uid": tr.req.uid, "slot": slot,
-                                      "bytes": nbytes})
 
         def admit_one(tr):
             """Give the request a slot (caller guarantees one is free)."""
+            with span(SPAN_SCHED_ADMIT, rec, uid=tr.req.uid):
+                admit(tr)
+
+        def admit(tr):
             if tr.state == SWAPPED:
                 resume(tr)
                 return
@@ -475,8 +485,10 @@ class ContinuousScheduler:
                 prefilling[slot] = tr
                 return
             tp = time.perf_counter()
-            logits1, state1, hit, padded = backend.prefill_one(tr.req)
-            pool.insert(state1, slot)
+            with span(SPAN_PREFILL, rec):
+                logits1, state1, hit, padded = backend.prefill_one(tr.req)
+            with span(SPAN_SPLICE, rec):
+                pool.insert(state1, slot)
             # per-request sample stream: token i <- fold_in(rkey, i),
             # independent of slot placement and co-scheduling
             rkey = request_key(seed, tr.req.uid)
@@ -497,8 +509,11 @@ class ContinuousScheduler:
                 if _prio(cand) <= _prio(victim):
                     return
                 slot = victim.slot
-                host = pool.swap_out(slot)
-                nbytes = _state_nbytes(host)
+                with span(SPAN_SCHED_PREEMPT, rec, uid=victim.req.uid,
+                          slot=slot, by_uid=cand.req.uid) as sp:
+                    host = pool.swap_out(slot)
+                    nbytes = _state_nbytes(host)
+                    sp.set(bytes=nbytes)
                 victim.host_state = host
                 victim.flight_pages = flight.suspend(slot)
                 del active[slot]
@@ -513,10 +528,6 @@ class ContinuousScheduler:
                     t_abs = abst(now())
                     board.event("preemptions", 1.0, t_abs)
                     board.event("swap_bytes", nbytes, t_abs)
-                self._trace.instant(
-                    SPAN_SCHED_PREEMPT, now(),
-                    args={"uid": victim.req.uid, "slot": slot,
-                          "bytes": nbytes, "by_uid": cand.req.uid})
                 queue.append(victim)
                 queue.remove(cand)
                 admit_one(cand)
@@ -528,23 +539,23 @@ class ContinuousScheduler:
             budget = chunk
             for tr in sorted(prefilling.values(), key=lambda t: t.order):
                 while budget > 0 and not tr.job.done:
-                    tc = time.perf_counter()
-                    n = tr.job.advance(budget)
-                    dt = time.perf_counter() - tc
-                    tr.prefill_s += dt
+                    with span(SPAN_PREFILL_CHUNK, rec,
+                              uid=tr.req.uid) as sp:
+                        tc = time.perf_counter()
+                        n = tr.job.advance(budget)
+                        tr.prefill_s += time.perf_counter() - tc
+                        sp.set(tokens=n, pos=tr.job.pos,
+                               total=len(tr.job.seq))
                     budget -= n
                     em.prefill_chunks += 1
                     em.prefill_chunk_tokens += n
-                    self._trace.complete(
-                        SPAN_PREFILL_CHUNK, tc - t0, dt,
-                        args={"uid": tr.req.uid, "tokens": n,
-                              "pos": tr.job.pos, "total": len(tr.job.seq)})
                 if tr.job.done:
                     slot = tr.slot
                     del prefilling[slot]
                     logits1, state1, hit, padded = tr.job.result
                     tr.job = None
-                    pool.insert(state1, slot)
+                    with span(SPAN_SPLICE, rec):
+                        pool.insert(state1, slot)
                     tr.metrics.prefix_hit_tokens = hit
                     tr.metrics.padded_prompt_tokens = padded
                     begin_decode(tr, slot, logits1,
@@ -556,11 +567,12 @@ class ContinuousScheduler:
                 or (svc is not None and not svc.closed):
             # -- live serving: drain arrivals + disconnects ---------------
             if svc is not None:
-                for r in svc.poll():
-                    queue.append(track(r))
-                cancels = svc.drain_cancels()
-                if cancels:
-                    cancel_pass(cancels)
+                with span(SPAN_FRONTEND_POLL, rec):
+                    for r in svc.poll():
+                        queue.append(track(r))
+                    cancels = svc.drain_cancels()
+                    if cancels:
+                        cancel_pass(cancels)
                 em.wall_s = now()       # keep live tokens/s meaningful
             # -- admission: refill freed slots at the host boundary (FIFO) -
             while queue and pool.free_count:
@@ -573,10 +585,12 @@ class ContinuousScheduler:
                 advance_prefill()
             if not active:
                 if svc is not None and not (queue or prefilling):
-                    svc.wait(0.002)     # idle: park until work arrives
+                    with span(SPAN_IDLE_WAIT, rec):
+                        svc.wait(0.002)  # idle: park until work arrives
                 continue
 
-            pool.flush_resets()          # lazily reset freed-but-idle slots
+            with span(SPAN_FLUSH_RESETS, rec):
+                pool.flush_resets()      # lazily reset freed-but-idle slots
             if on_device:
                 self._window_steps(backend, pool, em, lanes, apply_step,
                                    stop_turnover=bool(queue)
@@ -594,120 +608,138 @@ class ContinuousScheduler:
     # ------------------------------------------------------------------
     # decode dispatch modes
     # ------------------------------------------------------------------
-    def _trace_step(self, stats_np, live_slots, ts, dt):
-        """One decode step's trace spans (run-relative ts/dt seconds):
-        the step itself on the decode track, the recall-stage split
-        (blocking top-up vs overlapped stage) via TraceRecorder, and the
-        speculation counter track."""
-        tr = self._trace
-        agg = {k: float(sum(stats_np[k][s] for s in live_slots))
+    def _trace_counts(self, stats_np, mask, verify=()):
+        """The counts of one sync (``mask`` marks the committed steps of
+        the leading rows of ``stats_np``), stamped at the window's end: the
+        recall-page and speculation counter tracks, and one instant per
+        drafted verify iteration."""
+        rec = self._trace
+        if not rec.enabled:
+            return
+        t = time.perf_counter() - rec.origin
+        mask = np.asarray(mask, bool)
+        tot = {k: float(stats_np[k][:len(mask)][mask].sum())
                for k in ("sync_pages", "async_pages", "reused_pages",
                          "sel_pages", "spec_hit_pages", "corrected",
                          "kv_heads")}
-        tr.complete(SPAN_DECODE_STEP, ts, dt,
-                    args={"live_slots": len(live_slots),
-                          "sync_pages": agg["sync_pages"],
-                          "async_pages": agg["async_pages"]})
-        tr.recall_step(ts, dt, sync_pages=agg["sync_pages"],
-                       async_pages=agg["async_pages"],
-                       reused_pages=agg["reused_pages"],
-                       page_block_bytes=self._page_block_bytes)
-        tr.counter("speculation", ts, {
-            "hit_rate": (agg["spec_hit_pages"] / agg["sel_pages"]
-                         if agg["sel_pages"] else 0.0),
-            "correction_rate": (agg["corrected"] / agg["kv_heads"]
-                                if agg["kv_heads"] else 0.0)})
+        rec.counter(COUNTER_RECALL_PAGES, t, {
+            k: tot[k] for k in ("sync_pages", "async_pages", "reused_pages")})
+        rec.counter(COUNTER_SPECULATION, t, {
+            "hit_rate": (tot["spec_hit_pages"] / tot["sel_pages"]
+                         if tot["sel_pages"] else 0.0),
+            "correction_rate": (tot["corrected"] / tot["kv_heads"]
+                                if tot["kv_heads"] else 0.0)})
+        for args in verify:
+            rec.instant(SPAN_SPEC_VERIFY, t, args=args)
 
     def _window_steps(self, backend, pool, em, lanes, apply_step,
                       stop_turnover: bool, flight=None):
         """Host-sync-free mode: dispatch up to sync_interval fused steps,
         then sync once — pull the token/valid/stat blocks, apply them."""
-        loop = lanes.device_loop(stop_turnover, em)
-        ts = time.perf_counter()
-        ts_rel = ts - self._t0
-        state, loop, toks, valid, stats, n = backend.decode_window(
-            pool.state, loop)
-        pool.state = state
-        lanes.carry_back(loop)
-        n = int(n)                                  # the one host sync
-        toks_np = np.asarray(toks)
-        valid_np = np.asarray(valid)
-        stats_np = {k: (np.asarray(stats[k]) if k in stats
-                        else np.zeros(toks_np.shape, np.float32))
-                    for k in _STAT_KEYS}
-        dt = time.perf_counter() - ts
-        em.host_syncs += 1
-        pulled = (4 + toks_np.nbytes + valid_np.nbytes
-                  + sum(v.nbytes for v in stats_np.values()))
-        em.sync_bytes_to_host += pulled
-        self._trace.complete(SPAN_DECODE_WINDOW, ts_rel, dt,
-                             args={"steps": n, "bytes_to_host": pulled})
-        per_dt = dt / max(n, 1)
-        if toks_np.ndim == 3:
-            # speculative blocks (n, S, B): iteration j committed, per slot,
-            # the rows r with valid[j, r, slot] — an accept-longest prefix,
-            # so row 0's live set is the iteration's live set. Each row is
-            # applied as one logical decode step (per-token bookkeeping is
-            # row-exact); timestamps subdivide the iteration's wall share.
-            dl = toks_np.shape[1] - 1
-            for j in range(n):
-                rows = []
-                for r in range(dl + 1):
-                    live = [s for s in np.nonzero(valid_np[j, r])[0]]
-                    if live:
-                        rows.append((r, live))
-                if not rows:
-                    continue
-                base = rows[0][1]
-                committed = sum(len(live) for _, live in rows)
-                em.spec_verify_steps += 1
-                em.spec_slot_steps += len(base)
-                em.spec_proposed_tokens += dl * len(base)
-                em.spec_accepted_tokens += committed - len(base)
-                em.spec_committed_tokens += committed
-                ts_j = ts_rel + j * per_dt
-                if self._obs.enabled:
-                    em.observe_spec_step(committed / len(base))
-                self._trace.complete(
-                    SPAN_SPEC_VERIFY, ts_j, per_dt,
-                    args={"live_slots": len(base),
-                          "proposed": dl * len(base),
-                          "accepted": committed - len(base),
-                          "committed": committed})
-                # rejected rows' recall traffic was streamed for a
-                # continuation that never commits: dropped in flight (the
-                # rollback recall re-stages from the last committed row)
-                if flight is not None and dl:
-                    rej = float(sum(
-                        stats_np[k][j, r, s]
-                        for k in ("async_pages", "sync_pages")
-                        for r in range(1, dl + 1)
-                        for s in base if not valid_np[j, r, s]))
-                    if rej:
-                        flight.drop(rej)
-                sub = per_dt / len(rows)
-                for i, (r, live) in enumerate(rows):
-                    apply_step({k: stats_np[k][j, r] for k in _STAT_KEYS},
-                               toks_np[j, r], live, sub, ts=ts_j + i * sub,
-                               interpolated=True)
-            return
+        rec = self._trace
+        with span(SPAN_DECODE_WINDOW, rec) as win:
+            with span(SPAN_LANES, rec):
+                loop = lanes.device_loop(stop_turnover, em)
+            ts = time.perf_counter()
+            ts_rel = ts - self._t0
+            with span(SPAN_DISPATCH, rec):
+                state, loop, toks, valid, stats, n = backend.decode_window(
+                    pool.state, loop)
+            pool.state = state
+            lanes.carry_back(loop)
+            with span(SPAN_SYNC_WAIT, rec):
+                n = int(n)                          # the one host sync
+            with span(SPAN_PULL, rec):
+                toks_np = np.asarray(toks)
+                valid_np = np.asarray(valid)
+                stats_np = {k: (np.asarray(stats[k]) if k in stats
+                                else np.zeros(toks_np.shape, np.float32))
+                            for k in _STAT_KEYS}
+            dt = time.perf_counter() - ts
+            em.host_syncs += 1
+            pulled = (4 + toks_np.nbytes + valid_np.nbytes
+                      + sum(v.nbytes for v in stats_np.values()))
+            em.sync_bytes_to_host += pulled
+            win.set(steps=n, bytes_to_host=pulled)
+            per_dt = dt / max(n, 1)
+            verify = []
+            with span(SPAN_APPLY, rec):
+                if toks_np.ndim == 3:
+                    self._apply_spec(em, apply_step, flight, toks_np,
+                                     valid_np, stats_np, n, ts_rel, per_dt,
+                                     verify)
+                else:
+                    for j in range(n):
+                        live = [s for s in np.nonzero(valid_np[j])[0]]
+                        apply_step({k: stats_np[k][j] for k in _STAT_KEYS},
+                                   toks_np[j], live, per_dt,
+                                   ts=ts_rel + j * per_dt, interpolated=True)
+            self._trace_counts(stats_np, valid_np[:n], verify)
+
+    def _apply_spec(self, em, apply_step, flight, toks_np, valid_np,
+                    stats_np, n, ts_rel, per_dt, verify):
+        """Speculative blocks (n, S, B): iteration j committed, per slot,
+        the rows r with valid[j, r, slot] — an accept-longest prefix, so
+        row 0's live set is the iteration's live set. Each row is applied
+        as one logical decode step (per-token bookkeeping is row-exact);
+        timestamps subdivide the iteration's wall share. Each iteration's
+        counts are appended to ``verify``."""
+        dl = toks_np.shape[1] - 1
         for j in range(n):
-            live = [s for s in np.nonzero(valid_np[j])[0]]
-            apply_step({k: stats_np[k][j] for k in _STAT_KEYS},
-                       toks_np[j], live, per_dt, ts=ts_rel + j * per_dt,
-                       interpolated=True)
+            rows = []
+            for r in range(dl + 1):
+                live = [s for s in np.nonzero(valid_np[j, r])[0]]
+                if live:
+                    rows.append((r, live))
+            if not rows:
+                continue
+            base = rows[0][1]
+            committed = sum(len(live) for _, live in rows)
+            em.spec_verify_steps += 1
+            em.spec_slot_steps += len(base)
+            em.spec_proposed_tokens += dl * len(base)
+            em.spec_accepted_tokens += committed - len(base)
+            em.spec_committed_tokens += committed
+            ts_j = ts_rel + j * per_dt
+            if self._obs.enabled:
+                em.observe_spec_step(committed / len(base))
+            verify.append({"live_slots": len(base),
+                           "proposed": dl * len(base),
+                           "accepted": committed - len(base),
+                           "committed": committed})
+            # rejected rows' recall traffic was streamed for a
+            # continuation that never commits: dropped in flight (the
+            # rollback recall re-stages from the last committed row)
+            if flight is not None and dl:
+                rej = float(sum(
+                    stats_np[k][j, r, s]
+                    for k in ("async_pages", "sync_pages")
+                    for r in range(1, dl + 1)
+                    for s in base if not valid_np[j, r, s]))
+                if rej:
+                    flight.drop(rej)
+            sub = per_dt / len(rows)
+            for i, (r, live) in enumerate(rows):
+                apply_step({k: stats_np[k][j, r] for k in _STAT_KEYS},
+                           toks_np[j, r], live, sub, ts=ts_j + i * sub,
+                           interpolated=True)
 
     def _sync_step(self, backend, pool, em, lanes, apply_step):
         """Synchronous reference mode: one decode step, one host sync —
         tokens sampled outside the jitted step, stats pulled every step."""
-        loop = lanes.device_loop(False, em)
+        rec = self._trace
+        with span(SPAN_LANES, rec):
+            loop = lanes.device_loop(False, em)
         ts = time.perf_counter()
         ts_rel = ts - self._t0
-        logits, state, stats = backend.step(pool.state, loop["cur"][:, None])
-        toks = backend.sample_lanes(logits, loop["key"], loop["count"])
-        toks_np = np.asarray(toks)
-        stats_np = {k: (np.asarray(stats[k]) if k in stats
-                        else np.zeros(pool.num_slots)) for k in _STAT_KEYS}
+        with span(SPAN_STEP, rec):
+            logits, state, stats = backend.step(pool.state,
+                                                loop["cur"][:, None])
+            toks = backend.sample_lanes(logits, loop["key"], loop["count"])
+        with span(SPAN_SYNC_WAIT, rec):
+            toks_np = np.asarray(toks)
+            stats_np = {k: (np.asarray(stats[k]) if k in stats
+                            else np.zeros(pool.num_slots)) for k in _STAT_KEYS}
         dt = time.perf_counter() - ts
         pool.state = state
         em.host_syncs += 1
@@ -718,5 +750,8 @@ class ContinuousScheduler:
         # the next step re-uploads them — the per-step round trip the
         # host-sync-free loop exists to remove
         lanes.dirty = True
-        apply_step(stats_np, toks_np, [s for s in np.nonzero(~lanes.fin)[0]],
-                   dt, ts=ts_rel)
+        live = ~lanes.fin
+        with span(SPAN_APPLY, rec):
+            apply_step(stats_np, toks_np, [s for s in np.nonzero(live)[0]],
+                       dt, ts=ts_rel)
+        self._trace_counts(stats_np, live)

@@ -19,10 +19,11 @@ from repro.obs import (COUNT_BUCKETS, LATENCY_BUCKETS, RATE_BUCKETS,
                        validate_snapshot)
 from repro.obs.registry import (MetricsRegistry, SNAPSHOT_SCHEMA_VERSION,
                                 exponential_buckets, linear_buckets)
-from repro.obs.trace import (SPAN_DECODE_STEP, SPAN_DECODE_WINDOW,
-                             SPAN_RECALL_STAGED, SPAN_RECALL_TOPUP,
-                             SPAN_REQUEST_DECODE, SPAN_REQUEST_PREFILL,
-                             SPAN_REQUEST_QUEUED, annotate)
+from repro.obs.trace import (COUNTER_RECALL_PAGES, SPAN_APPLY,
+                             SPAN_DECODE_WINDOW, SPAN_DISPATCH, SPAN_LANES,
+                             SPAN_PULL, SPAN_REQUEST_DECODE,
+                             SPAN_REQUEST_PREFILL, SPAN_REQUEST_QUEUED,
+                             SPAN_SYNC_WAIT, annotate, span)
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.metrics import EngineMetrics
 from repro.serving.sampling import SamplerConfig
@@ -142,7 +143,7 @@ def test_write_jsonl_appends(tmp_path):
 # ---------------------------------------------------------------------------
 def test_trace_recorder_events_and_validation():
     tr = TraceRecorder(enabled=True)
-    tr.complete("engine/decode_step", 1.0, 0.002, args={"steps": 1})
+    tr.complete("engine/decode_window", 1.0, 0.002, args={"steps": 1})
     tr.instant("recall/reuse", 1.001)
     tr.counter("speculation", 1.0, {"hit_rate": 0.5})
     doc = tr.chrome_trace()
@@ -154,7 +155,29 @@ def test_trace_recorder_events_and_validation():
     # disabled recorder drops everything
     off = TraceRecorder(enabled=False)
     off.complete("x", 0.0, 1.0)
+    with span("engine/apply", off, uid=3) as sp:
+        sp.set(steps=2)
     assert off.events == []
+
+
+def test_span_records_its_own_interval():
+    """A span's X event is timed at its entry and exit on the recorder's
+    clock, and carries the args given at entry and those set before its
+    end; nested spans nest."""
+    import time
+    tr = TraceRecorder(enabled=True)
+    tr.set_origin(time.perf_counter())
+    with span("engine/decode_window", tr, slots=2) as win:
+        with span("engine/sync_wait", tr):
+            time.sleep(0.01)
+        win.set(steps=4)
+    assert validate_chrome_trace(tr.chrome_trace()) == []
+    inner, outer = [e for e in tr.events if e["ph"] == "X"]
+    assert inner["name"] == "engine/sync_wait"
+    assert outer["args"] == {"slots": 2, "steps": 4}
+    assert inner["dur"] >= 1e4                  # the 10 ms sleep, in us
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
 
 
 def test_trace_validator_catches_malformed():
@@ -181,21 +204,24 @@ def test_annotate_composes_with_jit():
 ARCH = "smollm-360m-smoke"
 
 
+def _requests(cfg, new_tokens=6, requests=3, context=64):
+    rng = np.random.default_rng(0)
+    return [Request(uid=i,
+                    tokens=rng.integers(0, cfg.vocab_size,
+                                        context).astype(np.int32),
+                    max_new_tokens=new_tokens)
+            for i in range(requests)]
+
+
 def _run_engine(obs, new_tokens=6, requests=3, context=64):
     cfg = get_config(ARCH)
     params = init_params(cfg, jax.random.PRNGKey(0))
     fkv = FreeKVConfig(method="freekv", page_size=8, budget=48, n_sink=8,
                        n_window=8, tau=0.8, sync_interval=4)
-    rng = np.random.default_rng(0)
-    reqs = [Request(uid=i,
-                    tokens=rng.integers(0, cfg.vocab_size,
-                                        context).astype(np.int32),
-                    max_new_tokens=new_tokens)
-            for i in range(requests)]
     eng = ServeEngine(cfg, fkv, params, max_len=context + new_tokens + 8,
                       batch_size=2, sampler=SamplerConfig(temperature=0.0),
                       scheduler="continuous", obs=obs)
-    outs = eng.generate(reqs)
+    outs = eng.generate(_requests(cfg, new_tokens, requests, context))
     return [c.tokens for c in outs], eng
 
 
@@ -268,25 +294,78 @@ def test_engine_trace_perfetto_wellformed(obs_on_off_runs, tmp_path):
     doc = tr.chrome_trace()
     assert validate_chrome_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"]}
+    em = eng_on.last_metrics
     for required in (SPAN_REQUEST_QUEUED, SPAN_REQUEST_PREFILL,
-                     SPAN_REQUEST_DECODE, SPAN_DECODE_WINDOW,
-                     SPAN_DECODE_STEP, SPAN_RECALL_TOPUP):
+                     SPAN_REQUEST_DECODE, SPAN_DECODE_WINDOW, SPAN_LANES,
+                     SPAN_DISPATCH, SPAN_SYNC_WAIT, SPAN_PULL, SPAN_APPLY,
+                     COUNTER_RECALL_PAGES):
         assert required in names, required
-    # staged DMA spans appear when the overlapped pipeline moved bytes
-    if eng_on.last_metrics.async_pages > 0:
-        assert SPAN_RECALL_STAGED in names
-    # decode-step spans nest inside their window on the engine track
-    wins = [e for e in doc["traceEvents"]
-            if e["name"] == SPAN_DECODE_WINDOW and e["ph"] == "X"]
-    steps = [e for e in doc["traceEvents"]
-             if e["name"] == SPAN_DECODE_STEP and e["ph"] == "X"]
-    assert wins and steps
-    lo = min(w["ts"] for w in wins)
-    hi = max(w["ts"] + w["dur"] for w in wins)
-    assert all(lo <= s["ts"] <= hi + 1 for s in steps)
+    # nothing is modelled or split out of a window any more
+    assert not names & {"engine/decode_step", "recall/topup",
+                        "recall/staged"}
+    # the page counter track sums to the engine's exact page counts
+    pages = [e["args"] for e in doc["traceEvents"]
+             if e["name"] == COUNTER_RECALL_PAGES and e["ph"] == "C"]
+    for k in ("sync_pages", "async_pages", "reused_pages"):
+        assert sum(p[k] for p in pages) == pytest.approx(getattr(em, k))
+    # one window span per host sync, each holding its children
+    ev = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    wins = [e for e in ev if e["name"] == SPAN_DECODE_WINDOW]
+    assert len(wins) == em.host_syncs == len(pages)
+    assert sum(w["args"]["steps"] for w in wins) == em.steps
+    for child in (SPAN_LANES, SPAN_DISPATCH, SPAN_SYNC_WAIT, SPAN_PULL,
+                  SPAN_APPLY):
+        kids = [e for e in ev if e["name"] == child]
+        assert len(kids) == len(wins), child
+        for w, k in zip(wins, kids):
+            assert w["ts"] <= k["ts"]
+            assert k["ts"] + k["dur"] <= w["ts"] + w["dur"]
     out = tmp_path / "t.json"
     tr.write(str(out))
     assert validate_chrome_trace(json.loads(out.read_text())) == []
+
+
+def test_engine_spans_land_in_the_profiler_trace(obs_on_off_runs, tmp_path):
+    """With observability off, a ``jax.profiler`` trace still holds the
+    engine loop's spans: every window's spans on one host line (the
+    engine thread), its children nested inside it, one sync wait per host
+    sync — and the run's tokens unchanged by the profiler."""
+    import glob
+    import os
+    tok_off, eng_off, _, _ = obs_on_off_runs
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0            # the TraceMe spans alone
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        outs = eng_off.generate(_requests(eng_off.cfg))
+    finally:
+        jax.profiler.stop_trace()
+    em = eng_off.last_metrics
+    assert [c.tokens for c in outs] == tok_off
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    window_spans = (SPAN_DECODE_WINDOW, SPAN_LANES, SPAN_DISPATCH,
+                    SPAN_SYNC_WAIT, SPAN_PULL, SPAN_APPLY)
+    found = {}                      # span name -> [(line, start, end)]
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name in window_spans:
+                    found.setdefault(e.name, []).append(
+                        ((plane.name, i), e.start_ns, e.end_ns))
+    lines = {ln for evs in found.values() for ln, _, _ in evs}
+    assert len(lines) == 1, lines
+    assert len(found[SPAN_SYNC_WAIT]) == em.host_syncs > 0
+    wins = sorted(found[SPAN_DECODE_WINDOW], key=lambda w: w[1])
+    assert len(wins) == em.host_syncs
+    for child in (SPAN_DISPATCH, SPAN_SYNC_WAIT, SPAN_PULL, SPAN_APPLY):
+        kids = sorted(found[child], key=lambda k: k[1])
+        assert len(kids) == len(wins), child
+        for (_, ws, we), (_, ks, ke) in zip(wins, kids):
+            assert ws <= ks and ke <= we, child
 
 
 def test_engine_metrics_summary_dedup():
