@@ -55,6 +55,13 @@ def layer_state(states, i):
     """Layer ``i``'s decode state out of a period-stacked scan carry — the
     one way every layer scan (decode, drafted verify, rewind) reads it.
 
+    ``layer_writeback`` updates the carry in place only where every read of
+    the old layer is ordered before the update by dataflow. A reader whose
+    result the new state does not depend on (FreeKV's old selection buffers,
+    read by attention) must read a materialised slice, or XLA copies the
+    whole stack, and back, once per layer: the retriever materialises that
+    read itself (``FreeKVRetriever.decode``).
+
     Host-resident leaves (the ``pinned_host`` pool) are not sliced: a slice
     of a host buffer is a copy of the layer's whole pool. The per-layer state
     carries the stack itself plus ``pool_layer``, and the host DMA kernels

@@ -292,6 +292,14 @@ class FreeKVRetriever:
     def decode(self, state, q, k_new, v_new, q_proxy=None):
         cfg, fkv = self.cfg, self.fkv
         p = fkv.page_size
+        # Attention reads the old selection buffers (``where(corrected,
+        # fresh, prev)``) while the new state holds only the fresh ones, so
+        # no dataflow orders that read before the layer scan writes the new
+        # buffers into the stacked carry in place. Read the old ones once,
+        # materialised: a read fused straight out of the stack makes XLA copy
+        # the whole layer stack, and back, once per layer.
+        state = dict(state, **jax.lax.optimization_barrier(
+            {k: state[k] for k in ("sel_k", "sel_v", "sel_idx")}))
         cur_pos = state["length"]                    # position of the new token
 
         if self._use_sharded(state):             # beyond-paper (§Perf)

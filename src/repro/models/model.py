@@ -712,10 +712,12 @@ def serve_step(cfg: ArchConfig, fkv: FreeKVConfig, params, state, tokens,
         stats_acc = {k: stats_acc[k] + s[k] for k in stats_acc}
 
     # NOTE: per-layer decode states live in the scan CARRY (read via
-    # dynamic_index, written back via dynamic_update) rather than as xs->ys.
-    # xs/ys would give the while-loop separate input and output buffers for
-    # the KV pool (2x the pool in temps, measured 18 GB/dev on
-    # deepseek-moe decode_32k); carried buffers are aliased in place.
+    # offload.layer_state, written back via layer_writeback) rather than as
+    # xs->ys. xs/ys would give the while-loop separate input and output
+    # buffers for the KV pool (2x the pool in temps, measured 18 GB/dev on
+    # deepseek-moe decode_32k). A carried buffer is updated in place only
+    # where every read of its old layer comes first by dataflow, else XLA
+    # copies the whole stack twice per layer (offload.layer_state).
     def scan_body(carry, xs):
         x, q_proxy, acc, states = carry
         lps, i = xs

@@ -1,5 +1,6 @@
 """Host-sync-free decode loop: donation (no per-step state copies),
-sync-interval bit-identity vs the synchronous path, per-slot RNG stream
+sync-interval bit-identity vs the synchronous path, the decode window's
+layer scan vs an unrolled per-layer reference, per-slot RNG stream
 stability across slot turnover, and host-transfer accounting."""
 import dataclasses
 import warnings
@@ -11,6 +12,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import FreeKVConfig
+from repro.models import layers as L
+from repro.models import model
 from repro.models.model import init_params
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.sampling import SamplerConfig, request_key
@@ -90,6 +93,78 @@ def test_eos_stops_mid_window(setup):
                             eos_token=eos)], batch_size=1)
     assert outs[0].tokens == full[0].tokens[:cut]
     assert outs[0].tokens[-1] == eos
+
+
+# ---------------------------------------------------------------------------
+# the layer scan's in-place carry vs an unrolled per-layer reference
+# ---------------------------------------------------------------------------
+def _per_layer_step(cfg, fkv, params, state, tok):
+    """One greedy decode step with the layer loop unrolled: each layer's
+    state is a plain slice of the stack and the new stack is rebuilt by
+    ``jnp.stack`` (no scan carry, no ``offload.layer_state``)."""
+    assert not cfg.prelude
+    x = L.embed_tokens(cfg, params["embed"], tok[:, None])
+    pos = state["pos"]
+    _, pat_r = model._retrievers(cfg, fkv)
+    q_proxy = jnp.zeros((x.shape[0], cfg.n_heads, cfg.d_head), x.dtype)
+    layers = [[] for _ in cfg.pattern]
+    for i in range(cfg.n_periods):
+        for pos_i, lk in enumerate(cfg.pattern):
+            lp = jax.tree.map(lambda a: a[i], params["pattern"][pos_i])
+            st = jax.tree.map(lambda a: a[i], state["pattern"][pos_i])
+            x, st, q_proxy, _ = model._apply_layer_decode(
+                cfg, fkv, lk, pat_r[pos_i], lp, x, pos, st, None, q_proxy)
+            layers[pos_i].append(st)
+    pattern = tuple(
+        jax.tree.map(lambda a, *ls: jnp.stack(ls).astype(a.dtype), old, *new)
+        for old, new in zip(state["pattern"], layers))
+    x = L.apply_norm(cfg, params["final_norm"], x)
+    tok = jnp.argmax(L.lm_logits(cfg, params["embed"], x[:, -1]), -1)
+    return tok.astype(jnp.int32), dict(state, pattern=pattern, pos=pos + 1)
+
+
+@pytest.mark.parametrize("arch,method,overlap", [
+    ("smollm-360m-smoke", "freekv", True),
+    ("smollm-360m-smoke", "freekv", False),
+    ("smollm-360m-smoke", "arkvale", True),
+    ("smollm-360m-smoke", "quest", True),
+    ("xlstm-350m-smoke", "freekv", True)])
+def test_decode_window_matches_per_layer_reference(arch, method, overlap):
+    """Greedy tokens and the whole returned state of a decode window over
+    three layers are bit-identical to the unrolled per-layer reference: the
+    scan's in-place carry, and how the retriever reads its old layer, change
+    no value."""
+    base = get_config(arch)
+    cfg = dataclasses.replace(base, n_periods=3,
+                              n_layers=3 * len(base.pattern))
+    fkv = FreeKVConfig(method=method, page_size=8, budget=64, n_sink=8,
+                       n_window=8, tau=0.8, recall_overlap=overlap)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    B, steps = 2, 6
+    prompt = np.stack([_prompt(cfg, 96, seed=s) for s in range(B)])
+    logits, state = model.prefill(cfg, fkv, params,
+                                  {"tokens": jnp.asarray(prompt)}, 256)
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    loop = {"cur": tok, "key": jnp.zeros((B, 2), jnp.uint32),
+            "count": jnp.zeros(B, jnp.int32),
+            "limit": jnp.full((B,), steps, jnp.int32),
+            "eos": jnp.full((B,), -1, jnp.int32),
+            "fin": jnp.zeros(B, bool), "stop_turnover": jnp.asarray(False)}
+    got_state, _, toks, valid, _, n = jax.jit(
+        lambda s, lp: model.decode_window(cfg, fkv, params, s, lp,
+                                          SamplerConfig(), k_max=steps))(
+        state, loop)
+    assert int(n) == steps and bool(jnp.all(valid))
+
+    ref_step = jax.jit(lambda s, t: _per_layer_step(cfg, fkv, params, s, t))
+    ref_toks = []
+    for _ in range(steps):
+        tok, state = ref_step(state, tok)
+        ref_toks.append(tok)
+    np.testing.assert_array_equal(np.asarray(toks), np.stack(ref_toks))
+    jax.tree_util.tree_map_with_path(
+        lambda path, a, b: np.testing.assert_array_equal(
+            a, b, err_msg=jax.tree_util.keystr(path)), got_state, state)
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ Widths: 8 KV heads, GQA group 4, d_head 128, 32-token pages, 4 slots of an
 fixture, so only the worker that runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,13 +113,39 @@ def test_recall_gather_quant_int8_compiles(shapes):
         shapes((B, KV, N_SEL), jnp.int32))
 
 
+_HLO_DTYPES = {"bf16": "bfloat16", "f32": "float32", "s32": "int32",
+               "u32": "uint32"}
+
+
+def _stacked_state_copies(text, pattern):
+    """The ``copy`` instructions outside the entry computation (so inside the
+    decode loop or a layer scan) whose result has the dtype and shape of a
+    layer-stacked device leaf of the decode state: each one copies a whole
+    stack where the layer scan should update it in place."""
+    stacked = {(jnp.dtype(a.dtype).name, tuple(a.shape))
+               for a in jax.tree.leaves(pattern)
+               if a.sharding.memory_kind != "pinned_host"}
+    found, entry = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        m = re.search(r"= (\w+)\[([\d,]*)\]\{[^}]*\} copy\(", line)
+        if m and not entry:
+            shape = tuple(int(d) for d in m.group(2).split(",") if d)
+            if (_HLO_DTYPES.get(m.group(1)), shape) in stacked:
+                found.append(line.strip())
+    return found
+
+
 @pytest.mark.parametrize("draft_len", [0, 2])
 def test_decode_window_host_pool_compiles(shapes, draft_len):
     """The engine's decode window (draft_len 0) and its drafted variant
     (verify scan + rewind scan) over a two-layer pinned_host pool, compiled
     kernels: every layer scan must reach the host pool through the DMA
     kernels only. A scan that slices the host stack makes XLA copy the pool
-    into a host temporary, so none may exist."""
+    into a host temporary, so none may exist. Every layer scan must update
+    the layer-stacked device state in place: no copy of a whole stack inside
+    the loops (the entry computation may relayout a stack once a window)."""
     import dataclasses
 
     from repro.configs import get_config
@@ -157,5 +184,6 @@ def test_decode_window_host_pool_compiles(shapes, draft_len):
     text = compiled.as_text()
     assert "recall_gather_host" in text and "write_host" in text
     assert compiled.memory_analysis().host_temp_size_in_bytes == 0
+    assert _stacked_state_copies(text, state["pattern"]) == []
     kinds = {s.memory_kind for s in jax.tree.leaves(compiled.output_shardings)}
     assert "pinned_host" in kinds
